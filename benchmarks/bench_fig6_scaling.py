@@ -47,16 +47,17 @@ def run(quiet: bool = False, device_counts=(1, 2, 4, 8)) -> list[dict]:
     rows = []
     base = None
     for ndev in device_counts:
-        env = dict(os.environ,
+        # the children emulate devices on the host CPU: they never claim
+        # an accelerator, which belongs to the parent process
+        env = dict(os.environ, JAX_PLATFORMS="cpu",
                    XLA_FLAGS=f"--xla_force_host_platform_device_count={ndev}",
                    PYTHONPATH=str(REPO / "src"))
         proc = subprocess.run(
             [sys.executable, "-c", _CHILD.format(ndev=ndev)],
             env=env, capture_output=True, text=True, timeout=560)
         if proc.returncode != 0:
-            rows.append({"bench": f"ndev{ndev}", "seconds": -1.0,
-                         "error": proc.stderr.strip()[-200:]})
-            continue
+            raise RuntimeError(f"ndev={ndev} child failed: "
+                               f"{proc.stderr.strip()[-2000:]}")
         line = [l for l in proc.stdout.splitlines()
                 if l.startswith("RESULT")][0]
         r = json.loads(line[len("RESULT"):])

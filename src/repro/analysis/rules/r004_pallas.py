@@ -20,7 +20,7 @@ Four checks on every ``pl.pallas_call`` site in ``kernels/``:
   equality cube (``lab[:, :, None] == lab[:, None, :]``, directly or via
   the shared ``argmax_tile_math`` tile math) allocates VMEM the
   BlockSpecs never see — its wrapper must assert the cube product
-  against a budget (``tile_b * d * d * 4 <= CUBE_BUDGET_BYTES``) before
+  against a budget (``tile_b * d * d * 4 <= CUBE_LIMIT_BYTES``) before
   launching, or an oversized tile choice OOMs only at Mosaic compile
   time on hardware.
 """
@@ -212,7 +212,7 @@ class PallasRule(Rule):
                     f"kernel '{kernel.name}' materialises the (B, D, D) "
                     f"equality cube — VMEM the BlockSpecs never see — but "
                     f"its wrapper has no cube-budget assert "
-                    f"(`tile_b * d * d * 4 <= CUBE_BUDGET_BYTES`)"))
+                    f"(`tile_b * d * d * 4 <= CUBE_LIMIT_BYTES`)"))
 
             nbytes = _block_nbytes(node, consts)
             if nbytes is not None and nbytes > self.vmem_ceiling:

@@ -76,12 +76,14 @@ def shard_graph(graph: Graph, mesh: Mesh, d_max: int | None = None,
         nw = np.concatenate([nw, np.zeros((extra, nw.shape[1]), np.float32)], 0)
         nmask = np.concatenate(
             [nmask, np.zeros((extra, nmask.shape[1]), bool)], 0)
+    # host arrays go straight to their shards; a jnp.asarray first would
+    # stage the whole tile array on one device
     spec = NamedSharding(mesh, P(_all_axes(mesh), None))
     return ShardedGraph(
         n=graph.n, n_pad=n_pad, d_max=nbr.shape[1],
-        nbr=jax.device_put(jnp.asarray(nbr), spec),
-        nw=jax.device_put(jnp.asarray(nw), spec),
-        nmask=jax.device_put(jnp.asarray(nmask), spec),
+        nbr=jax.device_put(nbr, spec),
+        nw=jax.device_put(nw, spec),
+        nmask=jax.device_put(nmask, spec),
     )
 
 

@@ -78,13 +78,16 @@ def build_graph(edges: np.ndarray, weights: np.ndarray | None = None,
         edges = np.concatenate([edges, edges[:, ::-1]], axis=0)
         weights = np.concatenate([weights, weights], axis=0)
 
-    # Merge duplicates: sort by (src, dst), sum weights over runs.
+    # Merge duplicates: sort by (src, dst), sum weights over runs (in the
+    # sorted order, one run after another).
     key = edges[:, 0] * n + edges[:, 1]
     order = np.argsort(key, kind="stable")
-    key, edges, weights = key[order], edges[order], weights[order]
-    uniq, inv = np.unique(key, return_inverse=True)
-    wsum = np.zeros(len(uniq), dtype=np.float64)
-    np.add.at(wsum, inv, weights)
+    key, weights = key[order], weights[order]
+    run_start = np.ones(len(key), dtype=bool)
+    run_start[1:] = key[1:] != key[:-1]
+    uniq = key[run_start]
+    wsum = np.bincount(np.cumsum(run_start) - 1, weights=weights,
+                       minlength=len(uniq))
     usrc = (uniq // n).astype(np.int32)
     udst = (uniq % n).astype(np.int32)
 
@@ -99,11 +102,10 @@ def build_graph(edges: np.ndarray, weights: np.ndarray | None = None,
     mask[:num_edges] = True
 
     row_ptr = np.zeros(n + 1, dtype=np.int64)
-    np.add.at(row_ptr[1:], usrc, 1)
-    row_ptr = np.cumsum(row_ptr).astype(np.int32)
+    row_ptr[1:] = np.cumsum(np.bincount(usrc, minlength=n))
+    row_ptr = row_ptr.astype(np.int32)
 
-    kdeg = np.zeros(n, dtype=np.float64)
-    np.add.at(kdeg, usrc, wsum)
+    kdeg = np.bincount(usrc, weights=wsum, minlength=n)
 
     graph = Graph(
         n=int(n), m_pad=int(m_pad), num_edges=int(num_edges),
@@ -170,24 +172,28 @@ def to_padded_neighbors(graph: Graph, d_max: int | None = None,
     weights, and validity.  ``n_pad`` rounds n up to 8 (sublane), ``d_max``
     rounds the max degree up to 128 (lane).  Pad neighbor ids point at the row
     vertex itself with weight 0 (self edges are excluded by construction, so a
-    0-weight self slot can never win the argmax).
+    0-weight self slot can never win the argmax).  A ``d_max`` below the
+    maximum degree raises: dropping edges would change the answer.
     """
-    row_ptr = np.asarray(graph.row_ptr)
+    row_ptr = np.asarray(graph.row_ptr).astype(np.int64)
     dst = np.asarray(graph.dst)[: graph.num_edges]
     wgt = np.asarray(graph.wgt)[: graph.num_edges]
     deg = row_ptr[1:] - row_ptr[:-1]
+    d_real = int(deg.max()) if len(deg) else 0
     if d_max is None:
-        d_max = max(int(deg.max()) if len(deg) else 1, 1)
+        d_max = max(d_real, 1)
+    if d_max < d_real:
+        raise ValueError(f"d_max={d_max} is below the graph's maximum "
+                         f"degree {d_real}; its edges would be dropped")
     d_max = _round_up(d_max, _LANE)
     n_pad = _round_up(graph.n, 8)
 
     nbr = np.repeat(np.arange(n_pad, dtype=np.int32)[:, None], d_max, axis=1)
     nw = np.zeros((n_pad, d_max), dtype=np.float32)
     nmask = np.zeros((n_pad, d_max), dtype=bool)
-    for i in range(graph.n):
-        lo, hi = int(row_ptr[i]), int(row_ptr[i + 1])
-        k = min(hi - lo, d_max)
-        nbr[i, :k] = dst[lo:lo + k]
-        nw[i, :k] = wgt[lo:lo + k]
-        nmask[i, :k] = True
+    rows = np.repeat(np.arange(graph.n), deg)
+    cols = np.arange(len(rows)) - np.repeat(row_ptr[:-1], deg)
+    nbr[rows, cols] = dst[: len(rows)]
+    nw[rows, cols] = wgt[: len(rows)]
+    nmask[rows, cols] = True
     return nbr, nw, nmask

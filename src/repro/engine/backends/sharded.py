@@ -29,8 +29,12 @@ from repro.core.distributed import (
     shard_graph,
 )
 from repro.core.graph import Graph
-from repro.engine.backends.tile import tile_rows
-from repro.engine.bucketing import BucketKey, pad_active, pad_labels
+from repro.engine.bucketing import (
+    BucketKey,
+    pad_active,
+    pad_labels,
+    tile_rows,
+)
 from repro.engine.cache import TRACE_LOG
 from repro.engine.config import EngineConfig
 from repro.engine.registry import BackendRun, register_backend

@@ -33,7 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.batch import warm_state_rows
-from repro.core.graph import Graph, _round_up, to_padded_neighbors
+from repro.core.graph import Graph, to_padded_neighbors
 from repro.core.lpa import _label_hash
 from repro.engine.bucketing import (
     BatchBucketKey,
@@ -41,17 +41,26 @@ from repro.engine.bucketing import (
     batch_index_arrays,
     pad_active,
     pad_labels,
+    tile_rows,
 )
 from repro.engine.cache import TRACE_LOG
 from repro.engine.config import EngineConfig
-from repro.engine.registry import BackendRun, BatchBackendRun, register_backend
+from repro.engine.registry import (
+    BackendRun,
+    BatchBackendRun,
+    register_backend,
+    tile_limit_error,
+)
 from repro.kernels import ops
 from repro.obs.convergence import batch_profiles, solo_profile
 
 
-def tile_rows(bucket_n: int) -> int:
-    """Row count of the padded tiles for a vertex bucket (sublane-aligned)."""
-    return _round_up(bucket_n, 8)
+def _check_admitted(n_bucket: int, d_bucket: int) -> None:
+    """Refuse a bucket the tile kernels cannot hold, before any trace."""
+    why = tile_limit_error(n_bucket, d_bucket)
+    if why is not None:
+        raise ValueError(f"tile backend refuses this graph: {why}; use "
+                         f"backend='segment' (or 'auto')")
 
 
 def pad_tile_rows(nbr: np.ndarray, nw: np.ndarray, nmask: np.ndarray,
@@ -85,6 +94,7 @@ class TileBackend:
         return ()
 
     def build(self, bucket: BucketKey, config: EngineConfig):
+        _check_admitted(bucket.n, bucket.d)
         rows = tile_rows(bucket.n)
         tau, max_iterations = config.tau, config.max_iterations
         mode = config.kernel_mode
@@ -493,6 +503,7 @@ class TileBackend:
     # standalone run would stop.
 
     def build_batch(self, bucket: BatchBucketKey, config: EngineConfig):
+        _check_admitted(bucket.n, bucket.d)
         rows = tile_rows(bucket.n)
         k1 = bucket.k + 1
         tau, max_iterations = config.tau, config.max_iterations

@@ -49,18 +49,31 @@ def max_degree(graph: Graph) -> int:
     return int(deg.max()) if len(deg) else 1
 
 
+def tile_rows(bucket_n: int) -> int:
+    """Row count of the padded tiles for a vertex bucket (sublane-aligned)."""
+    return _round_up(bucket_n, 8)
+
+
+def vertex_degree_bucket(n: int, d_real: int, *, bucketing: str = "pow2",
+                         min_vertex_bucket: int = 256) -> tuple[int, int]:
+    """(vertex bucket, lane-rounded degree bucket) for ``n`` vertices of
+    maximum degree ``d_real`` — the tile shapes a plan compiles at."""
+    d_real = max(d_real, 1)
+    if bucketing == "exact":
+        return n, _round_up(d_real, _LANE)
+    return (next_pow2(n, min_vertex_bucket),
+            _round_up(next_pow2(d_real), _LANE))
+
+
 def bucket_for(graph: Graph, *, bucketing: str = "pow2",
                min_vertex_bucket: int = 256,
                min_edge_bucket: int = 2048) -> BucketKey:
-    d_real = max(max_degree(graph), 1)
-    if bucketing == "exact":
-        return BucketKey(n=graph.n, m=graph.m_pad,
-                         d=_round_up(d_real, _LANE))
-    return BucketKey(
-        n=next_pow2(graph.n, min_vertex_bucket),
-        m=next_pow2(graph.m_pad, min_edge_bucket),
-        d=_round_up(next_pow2(d_real), _LANE),
-    )
+    n, d = vertex_degree_bucket(graph.n, max_degree(graph),
+                                bucketing=bucketing,
+                                min_vertex_bucket=min_vertex_bucket)
+    m = graph.m_pad if bucketing == "exact" \
+        else next_pow2(graph.m_pad, min_edge_bucket)
+    return BucketKey(n=n, m=m, d=d)
 
 
 def batch_bucket_for(batch, *, bucketing: str = "pow2",
@@ -68,16 +81,12 @@ def batch_bucket_for(batch, *, bucketing: str = "pow2",
                      min_edge_bucket: int = 2048) -> BatchBucketKey:
     """Bucket a :class:`repro.core.batch.GraphBatch`'s packed shapes."""
     g = batch.graph
-    d_real = max(max_degree(g), 1)
+    n, d = vertex_degree_bucket(g.n, max_degree(g), bucketing=bucketing,
+                                min_vertex_bucket=min_vertex_bucket)
     if bucketing == "exact":
-        return BatchBucketKey(k=batch.num_graphs, n=g.n, m=g.m_pad,
-                              d=_round_up(d_real, _LANE))
-    return BatchBucketKey(
-        k=next_pow2(batch.num_graphs),
-        n=next_pow2(g.n, min_vertex_bucket),
-        m=next_pow2(g.m_pad, min_edge_bucket),
-        d=_round_up(next_pow2(d_real), _LANE),
-    )
+        return BatchBucketKey(k=batch.num_graphs, n=n, m=g.m_pad, d=d)
+    return BatchBucketKey(k=next_pow2(batch.num_graphs), n=n,
+                          m=next_pow2(g.m_pad, min_edge_bucket), d=d)
 
 
 def batch_index_arrays(batch, k_bucket: int, n_rows: int,

@@ -108,6 +108,11 @@ class CompileCache:
     def __len__(self) -> int:
         return len(self._plans)
 
+    def plans(self) -> dict[Hashable, Any]:
+        """A snapshot of the cached plans by key."""
+        with self._lock:
+            return dict(self._plans)
+
     def clear(self) -> None:
         with self._lock:
             self._plans.clear()

@@ -38,9 +38,11 @@ from repro.engine.bucketing import (
     BatchBucketKey,
     BucketKey,
     max_degree,
-    next_pow2,
+    tile_rows,
+    vertex_degree_bucket,
 )
 from repro.engine.config import EngineConfig
+from repro.kernels.tiling import MAX_TILE_DEGREE
 
 
 class BackendRun(NamedTuple):
@@ -114,22 +116,46 @@ def backend_names() -> tuple[str, ...]:
     return tuple(sorted(_BACKENDS))
 
 
-# Auto-selection: the tile path materialises (rows, d_max) dense neighbor
+# Auto-selection: the tile path materialises (rows, d) dense neighbor
 # tiles — a win on TPU for degree-bounded graphs, a memory loss on skewed
-# ones.  Thresholds are deliberately simple and documented in README.md.
-_TILE_MAX_DEGREE = 1024
-_TILE_MAX_CELLS = 1 << 24  # ~150 MB of tiles at 9 B/cell
+# ones.  Both limits apply to the shapes a tile plan compiles at: bucket
+# rows by the lane-rounded degree bucket.  Documented in README.md.
+# From compiled.memory_analysis() for one TPU v5e (15.75 GB usable): the
+# fused tile propagate program at 2^27 cells (2^20 rows x 128) holds
+# 1.21 GB of arguments and 5.91 GB of temporaries; at 2^28 cells it needs
+# 14.25 GB, which leaves no room for the graph the engine also keeps on
+# the device.
+_TILE_MAX_CELLS = 1 << 27
+
+
+def tile_limit_error(n_bucket: int, d_bucket: int) -> str | None:
+    """Why the tile path refuses a (vertex bucket, degree bucket), or None."""
+    if d_bucket > MAX_TILE_DEGREE:
+        return (f"degree bucket {d_bucket} exceeds {MAX_TILE_DEGREE}, the "
+                f"widest tile row the kernels compile for")
+    cells = tile_rows(n_bucket) * d_bucket
+    if cells > _TILE_MAX_CELLS:
+        return (f"{cells} tile cells ({tile_rows(n_bucket)} rows x "
+                f"{d_bucket}) exceed the {_TILE_MAX_CELLS}-cell limit that "
+                f"fits one TPU v5e's HBM")
+    return None
+
+
+def _tile_or_segment(n: int, d_real: int, config: EngineConfig) -> str:
+    n_bucket, d_bucket = vertex_degree_bucket(
+        n, d_real, bucketing=config.bucketing,
+        min_vertex_bucket=config.min_vertex_bucket)
+    if jax.default_backend() == "tpu" \
+            and tile_limit_error(n_bucket, d_bucket) is None:
+        return "tile"
+    return "segment"
 
 
 def choose_backend(graph: Graph, config: EngineConfig) -> str:
     """Pick a backend from graph shape + device topology."""
     if jax.device_count() > 1 or config.mesh is not None:
         return "sharded"
-    d = next_pow2(max(max_degree(graph), 1))
-    if jax.default_backend() == "tpu" and d <= _TILE_MAX_DEGREE \
-            and graph.n * d <= _TILE_MAX_CELLS:
-        return "tile"
-    return "segment"
+    return _tile_or_segment(graph.n, max_degree(graph), config)
 
 
 def choose_backend_batch(graphs, config: EngineConfig) -> str:
@@ -141,9 +167,5 @@ def choose_backend_batch(graphs, config: EngineConfig) -> str:
     """
     if jax.device_count() > 1 or config.mesh is not None:
         return "sharded"
-    d = next_pow2(max(max(max_degree(g) for g in graphs), 1))
-    n_total = sum(g.n for g in graphs)
-    if jax.default_backend() == "tpu" and d <= _TILE_MAX_DEGREE \
-            and n_total * d <= _TILE_MAX_CELLS:
-        return "tile"
-    return "segment"
+    return _tile_or_segment(sum(g.n for g in graphs),
+                            max(max_degree(g) for g in graphs), config)

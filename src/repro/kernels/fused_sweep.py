@@ -23,8 +23,8 @@ Per-sub-sweep HBM tile traffic (B*D cells dominate; columns are O(B)):
            vs. separate 11 B/cell (min_label 9 + wake changed 1 + same 1)
 
 Block layout matches ``label_argmax``: grid over row tiles, (TILE_B, D)
-row tiles + (TILE_B, 1) state columns; the equality cube stays under the
-``tiling.CUBE_BUDGET_BYTES`` VMEM cap (asserted below, checked by R004).
+row tiles + (TILE_B, 1) state columns; the equality cube stays under
+``tiling.CUBE_LIMIT_BYTES`` (asserted below, checked by R004).
 
 Tie-breaks and the adopt rule are shared with the standalone kernels via
 ``argmax_tile_math`` so float sums are bit-identical across paths.
@@ -36,7 +36,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.label_argmax import argmax_tile_math
-from repro.kernels.tiling import CUBE_BUDGET_BYTES
+from repro.kernels.tiling import CUBE_LIMIT_BYTES
 
 _SENTINEL = 2147483647  # python literal: materialised in-trace, not captured
 
@@ -76,8 +76,7 @@ def fused_move_pallas(nbr_lab: jnp.ndarray, nbr_w: jnp.ndarray,
     """
     n_pad, d_max = nbr_lab.shape
     assert n_pad % tile_b == 0, (n_pad, tile_b)
-    assert tile_b == 1 or tile_b * d_max * d_max * 4 <= CUBE_BUDGET_BYTES, \
-        (tile_b, d_max)
+    assert tile_b * d_max * d_max * 4 <= CUBE_LIMIT_BYTES, (tile_b, d_max)
     grid = (n_pad // tile_b,)
 
     row_spec = pl.BlockSpec((tile_b, d_max), lambda i: (i, 0))

@@ -15,7 +15,7 @@ Block layout: grid over row tiles; each step sees (TILE_B, D) label /
 weight / mask tiles plus (TILE_B, 1) current-label column, and writes
 (TILE_B, 1) best-label / best-weight / current-weight columns.  VMEM per
 step: 3 * TILE_B * D * 4B for inputs + TILE_B * D * D * 4B for the equality
-cube — ``ops.py`` picks TILE_B so this stays well under 16 MB VMEM.
+cube — ``tiling.pick_tile_b`` picks TILE_B (see its budget rules).
 
 Tie-breaks match ``core.lpa`` exactly: max weight, then max label-hash
 (per-iteration seed), then min label.
@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.tiling import CUBE_BUDGET_BYTES
+from repro.kernels.tiling import CUBE_LIMIT_BYTES
 
 _SENTINEL = 2147483647  # python literal: materialised in-trace, not captured
 
@@ -85,8 +85,7 @@ def label_argmax_pallas(nbr_lab: jnp.ndarray, nbr_w: jnp.ndarray,
     """pallas_call wrapper.  Shapes: (n_pad, d_max) tiles, (n_pad,) cur."""
     n_pad, d_max = nbr_lab.shape
     assert n_pad % tile_b == 0, (n_pad, tile_b)
-    assert tile_b == 1 or tile_b * d_max * d_max * 4 <= CUBE_BUDGET_BYTES, \
-        (tile_b, d_max)
+    assert tile_b * d_max * d_max * 4 <= CUBE_LIMIT_BYTES, (tile_b, d_max)
     grid = (n_pad // tile_b,)
 
     row_spec = pl.BlockSpec((tile_b, d_max), lambda i: (i, 0))

@@ -5,9 +5,8 @@ default is the pure-jnp oracle (fast XLA:CPU path) with ``interpret=True``
 Pallas execution available for kernel-body validation (used by tests).
 
 VMEM budgeting: the label_argmax equality cube costs TILE_B * D * D * 4
-bytes; we target <= 4 MB for the cube (leaving headroom for the (TILE_B, D)
-operands, double-buffering, and the MXU accumulators in a 16 MB VMEM), and
-keep TILE_B a multiple of 8 (sublane) where possible.
+bytes; ``kernels/tiling.pick_tile_b`` sizes TILE_B (a multiple of 8, the
+sublane count Mosaic requires, targeting a 4 MB cube).
 """
 from __future__ import annotations
 
@@ -20,9 +19,7 @@ from repro.kernels import ref
 from repro.kernels.fused_sweep import fused_move_pallas, fused_split_pallas
 from repro.kernels.label_argmax import label_argmax_pallas
 from repro.kernels.min_label import min_label_pallas
-from repro.kernels.tiling import CUBE_BUDGET_BYTES, pick_tile_b
-
-_CUBE_BUDGET_BYTES = CUBE_BUDGET_BYTES  # re-export (see kernels/tiling.py)
+from repro.kernels.tiling import pick_tile_b
 
 __all__ = ["pick_tile_b", "label_argmax", "min_label", "fused_move",
            "fused_split", "resolve_fuse", "flash_attention"]

@@ -79,6 +79,17 @@ def serve(arch: str, reduced: bool = True, batch: int = 4,
     return {"generated": gen, "prefill_s": t_prefill, "decode_s": t_decode}
 
 
+def community_traffic(num_requests: int, size_classes=(150, 400, 900),
+                      avg_degree: float = 6.0, seed: int = 0) -> list:
+    """The synthetic request mix of ``serve_communities``: random graphs
+    drawn from a few size classes, deterministic in ``seed``."""
+    from repro.graphgen import erdos_renyi
+    rng = np.random.default_rng(seed)
+    return [erdos_renyi(int(rng.choice(size_classes)), avg_degree,
+                        seed=int(rng.integers(1 << 30)))
+            for _ in range(num_requests)]
+
+
 def serve_communities(num_requests: int = 24, backend: str = "auto",
                       size_classes=(150, 400, 900), avg_degree: float = 6.0,
                       seed: int = 0, max_batch: int = 8,
@@ -86,23 +97,22 @@ def serve_communities(num_requests: int = 24, backend: str = "auto",
                       graph_path: str | None = None):
     """Drive a community-detection request stream through the scheduler.
 
-    Requests (random graphs drawn from a few size classes — a traffic
-    mix) are **pre-generated outside the timed region**, submitted as a
+    Requests (``community_traffic``: random graphs drawn from a few size
+    classes) are **pre-generated outside the timed region**, submitted as a
     burst to a :class:`repro.launch.microbatch.MicroBatcher`, and drained
     in batches of up to ``max_batch`` with a ``batch_timeout_ms`` linger;
     each batch is one ``Engine.fit_many`` device dispatch.  Returns
-    per-request records + a summary dict (printed) with per-request
-    latency percentiles, the batch-size histogram, and aggregate edges/s.
+    per-request records (each with its result's labels) + a summary dict
+    (printed) with per-request latency percentiles, the batch-size
+    histogram, and aggregate edges/s.
     (Fresh-graph traffic, so every request is cold; evolving-graph
     traffic goes through ``--mode streaming``, where requests carry
     warm-start labels + delta frontiers through the same batcher.)
     """
     from repro.engine import Engine, EngineConfig
-    from repro.graphgen import erdos_renyi
     from repro.launch.microbatch import MicroBatcher
 
     eng = Engine(EngineConfig(backend=backend))
-    rng = np.random.default_rng(seed)
     # generation stays outside the timed region: request timers measure
     # serving latency, not graphgen (nor file ingest — a real graph is
     # loaded once through the parse-once CSR store up front)
@@ -121,9 +131,8 @@ def serve_communities(num_requests: int = 24, backend: str = "auto",
         # repeat fits still exercise the compile + warm caches.
         max_batch = 1
     else:
-        graphs = [erdos_renyi(int(rng.choice(size_classes)), avg_degree,
-                              seed=int(rng.integers(1 << 30)))
-                  for _ in range(num_requests)]
+        graphs = community_traffic(num_requests, size_classes, avg_degree,
+                                   seed)
 
     batcher = MicroBatcher(eng, max_batch=max_batch,
                            batch_timeout_ms=batch_timeout_ms,
@@ -138,7 +147,7 @@ def serve_communities(num_requests: int = 24, backend: str = "auto",
     records = [{"n": g.n, "edges": g.num_edges, "bucket": r.bucket,
                 "backend": r.backend, "cache_hit": r.cache_hit,
                 "batch_size": s.batch_size, "latency_s": s.latency_s,
-                "communities": r.num_communities}
+                "communities": r.num_communities, "labels": r.labels}
                for g, s, r in zip(graphs, subs, results)]
 
     total_edges = sum(g.num_edges for g in graphs)
@@ -456,4 +465,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import configure_compile_cache
+    configure_compile_cache()
     main()

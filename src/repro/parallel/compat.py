@@ -1,63 +1,39 @@
-"""Version-compat shims for jax APIs that moved between releases.
+"""Mesh and shard_map construction in one place.
 
-The repo targets the modern spellings (``jax.shard_map`` with
-``check_vma``, ``jax.make_mesh(..., axis_types=...)``); on older jax
-(0.4.x, as baked into this container) those fall back to
-``jax.experimental.shard_map`` / ``check_rep`` and an ``axis_types``-free
-``make_mesh``.  All mesh and shard_map construction in the repo goes
-through this module so the compat logic lives in exactly one place.
+All mesh and shard_map construction in the repo goes through this module,
+so every mesh gets ``Auto`` axis types and every shard_map spells its
+replication check the same way.
 """
 from __future__ import annotations
 
 import jax
 
-try:  # jax >= 0.5: top-level export, `check_vma` kwarg
-    from jax import shard_map as _shard_map
-    _CHECK_KW = "check_vma"
-except ImportError:  # jax 0.4.x: experimental, `check_rep` kwarg
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _CHECK_KW = "check_rep"
-
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool | None = None):
-    """``jax.shard_map`` with the replication-check kwarg spelled per-version."""
-    kw = {} if check_vma is None else {_CHECK_KW: check_vma}
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kw)
+    """``jax.shard_map``; ``check_vma=None`` keeps jax's default."""
+    kw = {} if check_vma is None else {"check_vma": check_vma}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kw)
 
 
 def auto_axis_types(n_axes: int):
-    """``(AxisType.Auto,) * n_axes`` where supported, else None."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return None
-    return (axis_type.Auto,) * n_axes
+    """``(AxisType.Auto,) * n_axes``."""
+    return (jax.sharding.AxisType.Auto,) * n_axes
 
 
 def cost_analysis_dict(compiled) -> dict:
-    """``Compiled.cost_analysis()`` as a flat dict on every jax version
-    (jax 0.4.x returns a one-element list of dicts, newer jax a dict)."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost or {})
+    """``Compiled.cost_analysis()`` as a plain dict."""
+    return dict(compiled.cost_analysis() or {})
 
 
 def abstract_mesh(shape, axes):
-    """``jax.sharding.AbstractMesh`` across its two historical signatures."""
-    cls = jax.sharding.AbstractMesh
-    try:  # modern: (axis_sizes, axis_names, axis_types=...)
-        axis_types = auto_axis_types(len(axes))
-        kw = {} if axis_types is None else {"axis_types": axis_types}
-        return cls(tuple(shape), tuple(axes), **kw)
-    except TypeError:  # jax 0.4.x: (((name, size), ...),)
-        return cls(tuple(zip(axes, shape)))
+    """``jax.sharding.AbstractMesh`` with Auto axis types."""
+    return jax.sharding.AbstractMesh(tuple(shape), tuple(axes),
+                                     axis_types=auto_axis_types(len(axes)))
 
 
 def make_mesh(shape, axes, *, devices=None):
-    """``jax.make_mesh`` with Auto axis types when the jax version has them."""
-    axis_types = auto_axis_types(len(axes))
-    kw = {} if axis_types is None else {"axis_types": axis_types}
-    if devices is not None:
-        kw["devices"] = devices
-    return jax.make_mesh(tuple(shape), tuple(axes), **kw)
+    """``jax.make_mesh`` with Auto axis types."""
+    kw = {} if devices is None else {"devices": devices}
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=auto_axis_types(len(axes)), **kw)

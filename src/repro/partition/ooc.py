@@ -144,9 +144,9 @@ def choose_partition_backend(config: EngineConfig, d_bucket: int,
     the driver is a single-device streaming loop)."""
     import jax
 
-    from repro.engine.registry import _TILE_MAX_CELLS, _TILE_MAX_DEGREE
-    if (jax.default_backend() == "tpu" and d_bucket <= _TILE_MAX_DEGREE
-            and n * d_bucket <= _TILE_MAX_CELLS):
+    from repro.engine.registry import tile_limit_error
+    if (jax.default_backend() == "tpu"
+            and tile_limit_error(n, d_bucket) is None):
         return "tile"
     return "segment"
 

@@ -233,3 +233,32 @@ def test_fused_fit_many_parity():
         assert f.lpa_iterations == b.lpa_iterations
         assert f.split_iterations == b.split_iterations
         assert f.batch_size == b.batch_size == 3
+
+
+def test_tile_admission_counts_bucket_cells():
+    """Cells are counted at the compiled shapes: bucket rows times the
+    lane-rounded degree bucket.  A 2048x2048 grid (degree 4, so 128-wide
+    rows) is refused; a 1024x1024 grid is admitted."""
+    from repro.engine.bucketing import vertex_degree_bucket
+    from repro.engine.registry import tile_limit_error
+    assert tile_limit_error(*vertex_degree_bucket(1 << 20, 4)) is None
+    assert "cells" in tile_limit_error(*vertex_degree_bucket(1 << 22, 4))
+    assert "degree bucket" in tile_limit_error(256, 2048)
+
+
+def test_forced_tile_refuses_unadmitted_graph():
+    """backend="tile" on a graph wider than the kernels' widest row raises
+    a ValueError before anything is traced, solo and batched."""
+    from repro.core.graph import build_graph
+    from repro.engine import TRACE_LOG
+    star = build_graph(np.stack([np.zeros(1500, np.int64),
+                                 np.arange(1, 1501)], axis=1), n=1501)
+    before = TRACE_LOG.snapshot()
+    eng = fresh_engine(backend="tile")
+    with pytest.raises(ValueError, match="tile backend refuses"):
+        eng.fit(star)
+    with pytest.raises(ValueError, match="tile backend refuses"):
+        eng.fit_many([star, erdos_renyi(40, 3.0, seed=1)])
+    assert TRACE_LOG.snapshot() == before
+    # auto falls back to segment and still answers
+    assert fresh_engine().fit(star).backend == "segment"

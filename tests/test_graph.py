@@ -54,6 +54,16 @@ def test_padded_neighbors_roundtrip():
     assert ((nbr == self_rows) | nmask).all()
 
 
+def test_padded_neighbors_refuses_truncation():
+    """A d_max below the maximum degree raises instead of dropping edges."""
+    star = build_graph(np.stack([np.zeros(200, np.int64),
+                                 np.arange(1, 201)], axis=1), n=201)
+    with pytest.raises(ValueError, match="maximum degree 200"):
+        to_padded_neighbors(star, d_max=128)
+    nbr, _nw, nmask = to_padded_neighbors(star, d_max=200)
+    assert nbr.shape[1] == 256 and int(nmask[0].sum()) == 200
+
+
 @settings(max_examples=20, deadline=None)
 @given(st.integers(2, 60), st.integers(0, 10_000))
 def test_build_graph_properties(n, seed):

@@ -7,6 +7,8 @@ pytest.importorskip(
 from hypothesis import given, settings, strategies as st  # noqa: E402
 
 from repro.core import modularity
+from repro.core.graph import build_graph
+from repro.core.modularity import modularity_host
 from repro.graphgen import karate_club, ring_of_cliques
 from conftest import random_graph
 
@@ -38,13 +40,30 @@ def test_karate_known_split():
 def test_bounds_and_invariance(n, seed, n_comm):
     g = random_graph(n, 4.0, seed=seed, weighted=True)
     rng = np.random.default_rng(seed)
-    comm = rng.integers(0, n_comm, size=n).astype(np.int32)
+    # modularity's contract: community labels are vertex ids in [0, n)
+    comm = rng.integers(0, min(n_comm, n), size=n).astype(np.int32)
     q = float(modularity(g, jnp.asarray(comm)))
     assert -0.5 - 1e-6 <= q <= 1.0 + 1e-6
-    # invariant under community relabeling
-    perm = rng.permutation(n_comm).astype(np.int32)
+    assert q == pytest.approx(modularity_host(g, comm), abs=1e-5)
+    # invariant under community relabeling within [0, n)
+    perm = rng.permutation(n).astype(np.int32)
     q2 = float(modularity(g, jnp.asarray(perm[comm])))
     assert q == pytest.approx(q2, abs=1e-5)
+
+
+@pytest.mark.parametrize("split", [False, True], ids=["one", "two"])
+def test_float32_sums_match_float64_host(split):
+    """A heavy edge beside thousands of light ones: summing community
+    degrees one term after another in float32 drops the light terms
+    (off by ~1e-4 here); pairwise sums stay at float32 rounding."""
+    n = 3001
+    edges = np.array([(0, 1), (1, 2)] + [(i, i + 1) for i in range(2, n - 1)])
+    w = np.array([2.0 ** 20] + [1 / 16] * (len(edges) - 1))
+    g = build_graph(edges, w, n=n)
+    comm = (np.arange(n) >= n // 2) if split else np.zeros(n)
+    comm = comm.astype(np.int32)
+    q = float(modularity(g, jnp.asarray(comm)))
+    assert q == pytest.approx(modularity_host(g, comm), abs=1e-5)
 
 
 def test_single_community_zero():

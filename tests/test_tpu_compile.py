@@ -1,0 +1,119 @@
+"""Ahead-of-time compiles of the tile path for one TPU v5e chip.
+
+Nothing runs on a chip here: the TPU compiler builds each program for a
+described (not attached) v5e, which refuses what Mosaic or the chip's
+memory would refuse — misaligned blocks, VMEM overflow, HBM overflow.
+The topology is described inside a module fixture, never at import:
+only one process at a time may load the TPU library, and every test
+worker imports this file.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.engine import EngineConfig
+from repro.engine.bucketing import BucketKey
+from repro.engine.registry import get_backend, tile_limit_error
+from repro.kernels import ops
+from repro.kernels.tiling import MAX_TILE_DEGREE, pick_tile_b
+
+# One v5e chip's usable HBM, as the TPU compiler reports it ("15.75G").
+V5E_HBM_BYTES = int(15.75 * 2 ** 30)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    # a compile for a described chip cannot be read back from the
+    # persistent cache without that chip
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "cannot describe"
+        jax.config.update("jax_enable_compilation_cache", prev)
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prev)
+
+
+def _spec(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _kernel_args(sharding, name, n, d):
+    tile = lambda dt: _spec(sharding, (n, d), dt)  # noqa: E731
+    col = lambda dt: _spec(sharding, (n,), dt)  # noqa: E731
+    seed = _spec(sharding, (), jnp.int32)
+    i32, f32, b = jnp.int32, jnp.float32, jnp.bool_
+    if name == "label_argmax":
+        return ops.label_argmax, (tile(i32), tile(f32), tile(b), col(i32),
+                                  seed), {}
+    if name == "min_label":
+        return ops.min_label, (tile(i32), tile(i32), tile(b), col(i32),
+                               col(i32)), {}
+    if name == "fused_move":
+        return ops.fused_move, (tile(i32), tile(f32), tile(b), tile(b),
+                                col(i32), col(b), col(b), col(b), col(b),
+                                seed), {}
+    return ops.fused_split, (tile(i32), tile(i32), tile(b), tile(b),
+                             col(i32), col(i32)), {"prune": True}
+
+
+@pytest.mark.parametrize("n,d", [(1024, 128), (1024, MAX_TILE_DEGREE),
+                                 (120, 128)],
+                         ids=["d128", "widest", "exact_n120"])
+@pytest.mark.parametrize("name", ["label_argmax", "min_label", "fused_move",
+                                  "fused_split"])
+def test_kernel_compiles_for_v5e(one_chip, name, n, d):
+    """Each tile kernel compiles to a Mosaic custom call at the tile that
+    pick_tile_b chooses, at d=128, at the widest admitted width, and at
+    the 120-row shape that ``bucketing="exact"`` produces."""
+    fn, args, kw = _kernel_args(one_chip, name, n, d)
+    compiled = fn.lower(*args, mode="pallas", **kw).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_pick_tile_b_is_mosaic_aligned():
+    """Every tile is a multiple of 8 that divides n_pad, or n_pad itself."""
+    for n_pad in (8, 16, 24, 40, 56, 120, 136, 1000, 1024, 4096, 12, 5, 1):
+        for d in (128, 256, 384, 512, 640, 1024):
+            t = pick_tile_b(n_pad, d)
+            assert n_pad % t == 0, (n_pad, d, t)
+            assert t % 8 == 0 or t == n_pad, (n_pad, d, t)
+
+
+@pytest.mark.parametrize("n_bucket,d", [(1 << 20, 128),
+                                        (1 << 17, MAX_TILE_DEGREE)],
+                         ids=["cell_limit", "degree_limit"])
+def test_largest_admitted_tile_plan_fits_hbm(one_chip, n_bucket, d):
+    """The largest buckets the auto policy admits compile for one v5e with
+    their propagate and split programs each in at most half its HBM (the
+    rest holds the graph itself); twice the rows is refused."""
+    assert tile_limit_error(n_bucket, d) is None
+    assert tile_limit_error(2 * n_bucket, d) is not None
+    plan = get_backend("tile").build(BucketKey(n_bucket, 2048, d),
+                                     EngineConfig(kernel_mode="pallas"))
+    r = plan.rows
+    tiles = (_spec(one_chip, (r, d), jnp.int32),
+             _spec(one_chip, (r, d), jnp.float32),
+             _spec(one_chip, (r, d), jnp.bool_))
+    col = lambda dt: _spec(one_chip, (r,), dt)  # noqa: E731
+    n_real = _spec(one_chip, (), jnp.int32)
+    programs = {
+        "propagate": plan.propagate.lower(*tiles, n_real, col(jnp.int32),
+                                          col(jnp.bool_)),
+        "split": plan.split.lower(tiles[0], tiles[2], col(jnp.int32),
+                                  col(jnp.int32), n_real),
+    }
+    for name, lowered in programs.items():
+        compiled = lowered.compile()
+        assert "tpu_custom_call" in compiled.as_text(), name
+        ma = compiled.memory_analysis()
+        total = (ma.argument_size_in_bytes + ma.temp_size_in_bytes
+                 + ma.output_size_in_bytes)
+        assert total <= V5E_HBM_BYTES // 2, (name, total)
