@@ -2,9 +2,13 @@
 
 The observability layer (``repro.obs``) is host-side bookkeeping by
 contract: spans and registry writes wrap *stage boundaries* (engine
-prepare/dispatch/compact, ooc phases, serving admission→settle), never
-the per-sweep inner loops, and convergence profiles record device-side
-into preallocated buffers precisely so no telemetry runs per sweep.
+prepare/propagate/split/compact, ooc phases, serving admission→settle),
+never the per-sweep inner loops, and convergence profiles record
+device-side into preallocated buffers precisely so no telemetry runs per
+sweep.  The in-program device scope is ``jax.named_scope`` (the
+``sweep.*`` scopes of the sweep bodies): it only names operations in the
+compiled program's metadata, costs nothing at run time, and is allowed
+inside traced code.
 This rule enforces that contract inside the hot modules (``core/``,
 ``kernels/``, ``engine/backends/``):
 

@@ -257,13 +257,15 @@ def lpa_run_batched(graph: Graph, sizes: jnp.ndarray, graph_id: jnp.ndarray,
         running = ~done[graph_id]
         dn = jnp.zeros((k1,), jnp.int32)
         for sweep, klass in enumerate((~parity, parity)):
-            cand = active & klass & running
+            with jax.named_scope("sweep.wake"):
+                cand = active & klass & running
             labels, changed, _ = lpa_move(graph, labels, cand,
                                           2 * it + sweep)
-            active = (active & ~cand) | neighbors_of(graph, changed)
-            sc = jax.ops.segment_sum(changed.astype(jnp.int32),
-                                     graph_id, num_segments=k1)
-            dn = dn + sc
+            with jax.named_scope("sweep.wake"):
+                active = (active & ~cand) | neighbors_of(graph, changed)
+                sc = jax.ops.segment_sum(changed.astype(jnp.int32),
+                                         graph_id, num_segments=k1)
+                dn = dn + sc
             if profile:
                 buf = buf.at[2 * it + sweep].set(jnp.stack(
                     [jax.ops.segment_sum(cand.astype(jnp.int32), graph_id,
@@ -313,8 +315,9 @@ def split_lp_batched(graph: Graph, sizes: jnp.ndarray, graph_id: jnp.ndarray,
         buf = s[4] if profile_rows else None
         new, nxt_active, changed, _ = _min_label_sweep(
             graph, comm, labels, active, prune, shortcut, voffset=voffset)
-        dn = jax.ops.segment_sum(changed.astype(jnp.int32), graph_id,
-                                 num_segments=k1)
+        with jax.named_scope("sweep.wake"):
+            dn = jax.ops.segment_sum(changed.astype(jnp.int32), graph_id,
+                                     num_segments=k1)
         if profile_rows:
             row = jnp.minimum(iters.max(), profile_rows - 1)
             buf = buf.at[row].set(jnp.stack(

@@ -32,6 +32,21 @@ cites (Cordasco & Gargano): vertices are statically split into two hashed
 parity classes and each ``lpa_run`` iteration performs one sub-sweep per
 class — updates in sweep A are visible to sweep B, recovering most of the
 asynchronous behaviour while staying data-parallel and deterministic.
+
+Every sweep body (here, in ``core/split.py``, ``core/batch.py`` and the
+tile backend) names its operations with ``jax.named_scope`` so that a
+profiler trace can split sweep device time by phase.  The scopes live
+only in the compiled program's ``op_name`` metadata; HLO op and module
+names, and the run time, are unchanged:
+
+* ``sweep.gather`` — neighbour-indexed reads (``labels[graph.dst]``,
+  ``labels[nbr]``, ``best_w[seg_src]``, ...) and the expressions fused
+  around them;
+* ``sweep.sort`` — the (source, label) sort of ``_scan_communities``;
+* ``sweep.reduce`` — the per-row reductions and the adopt rule: segment
+  reductions and the Pallas row kernels;
+* ``sweep.wake`` — the frontier: changed masks, their counts, and which
+  rows run next (``neighbors_of``, ``jnp.any(changed[nbr] & ...)``).
 """
 from __future__ import annotations
 
@@ -68,23 +83,30 @@ def _scan_communities(graph: Graph, labels: jnp.ndarray,
     """
     n, m_pad = graph.n, graph.m_pad
     bound = n if label_bound is None else label_bound
-    # Padding edges get the label sentinel so they sort last and never match.
-    lab_dst = jnp.where(graph.edge_mask, labels[graph.dst],
-                        bound).astype(jnp.int32)
-    src = jnp.where(graph.edge_mask, graph.src, n).astype(jnp.int32)
-    src_s, lab_s, wgt_s = jax.lax.sort((src, lab_dst, graph.wgt), num_keys=2)
+    with jax.named_scope("sweep.gather"):
+        # Padding edges get the label sentinel so they sort last and
+        # never match.
+        lab_dst = jnp.where(graph.edge_mask, labels[graph.dst],
+                            bound).astype(jnp.int32)
+        src = jnp.where(graph.edge_mask, graph.src, n).astype(jnp.int32)
+    with jax.named_scope("sweep.sort"):
+        src_s, lab_s, wgt_s = jax.lax.sort((src, lab_dst, graph.wgt),
+                                           num_keys=2)
 
-    prev_src = jnp.concatenate([jnp.full((1,), -1, jnp.int32), src_s[:-1]])
-    prev_lab = jnp.concatenate([jnp.full((1,), -1, jnp.int32), lab_s[:-1]])
-    is_start = (src_s != prev_src) | (lab_s != prev_lab)
-    run_id = jnp.cumsum(is_start.astype(jnp.int32)) - 1  # (m_pad,) in [0, R)
+    with jax.named_scope("sweep.reduce"):
+        prev_src = jnp.concatenate([jnp.full((1,), -1, jnp.int32),
+                                    src_s[:-1]])
+        prev_lab = jnp.concatenate([jnp.full((1,), -1, jnp.int32),
+                                    lab_s[:-1]])
+        is_start = (src_s != prev_src) | (lab_s != prev_lab)
+        run_id = jnp.cumsum(is_start.astype(jnp.int32)) - 1  # in [0, R)
 
-    run_wgt = jax.ops.segment_sum(wgt_s, run_id, num_segments=m_pad)
-    run_src = jax.ops.segment_max(src_s, run_id, num_segments=m_pad)
-    run_lab = jax.ops.segment_max(lab_s, run_id, num_segments=m_pad)
-    run_valid = (jax.ops.segment_max(is_start.astype(jnp.int32), run_id,
-                                     num_segments=m_pad) > 0)
-    run_valid &= (run_lab < bound) & (run_src < n)
+        run_wgt = jax.ops.segment_sum(wgt_s, run_id, num_segments=m_pad)
+        run_src = jax.ops.segment_max(src_s, run_id, num_segments=m_pad)
+        run_lab = jax.ops.segment_max(lab_s, run_id, num_segments=m_pad)
+        run_valid = (jax.ops.segment_max(is_start.astype(jnp.int32), run_id,
+                                         num_segments=m_pad) > 0)
+        run_valid &= (run_lab < bound) & (run_src < n)
     return run_src, run_lab, run_wgt, run_valid
 
 
@@ -100,9 +122,10 @@ def _label_hash(labels: jnp.ndarray, iteration: jnp.ndarray) -> jnp.ndarray:
 
 def neighbors_of(graph: Graph, mask: jnp.ndarray) -> jnp.ndarray:
     """Boolean mask of vertices adjacent to any vertex in ``mask``."""
-    return jax.ops.segment_max(
-        (mask[graph.dst] & graph.edge_mask).astype(jnp.int32),
-        graph.src, num_segments=graph.n) > 0
+    with jax.named_scope("sweep.wake"):
+        return jax.ops.segment_max(
+            (mask[graph.dst] & graph.edge_mask).astype(jnp.int32),
+            graph.src, num_segments=graph.n) > 0
 
 
 def lpa_move(graph: Graph, labels: jnp.ndarray, active: jnp.ndarray,
@@ -118,28 +141,38 @@ def lpa_move(graph: Graph, labels: jnp.ndarray, active: jnp.ndarray,
     bound = n if label_bound is None else label_bound
     run_src, run_lab, run_wgt, run_valid = _scan_communities(graph, labels,
                                                              label_bound)
-    seg_src = jnp.where(run_valid, run_src, n - 1)  # dump invalid runs on a real id
-    w = jnp.where(run_valid, run_wgt, _NEG)
+    with jax.named_scope("sweep.reduce"):
+        # dump invalid runs on a real id
+        seg_src = jnp.where(run_valid, run_src, n - 1)
+        w = jnp.where(run_valid, run_wgt, _NEG)
 
-    # Step 4: per-source best community weight; tie-break max label hash.
-    best_w = jax.ops.segment_max(w, seg_src, num_segments=n)
-    is_best = run_valid & (run_wgt >= best_w[seg_src]) & (best_w[seg_src] > 0)
-    run_h = _label_hash(run_lab, jnp.asarray(iteration, jnp.int32))
-    best_h = jax.ops.segment_max(jnp.where(is_best, run_h, -1), seg_src,
-                                 num_segments=n)
-    pick = is_best & (run_h == best_h[seg_src])
-    best_lab = jax.ops.segment_min(jnp.where(pick, run_lab, bound), seg_src,
-                                   num_segments=n)
+        # Step 4: per-source best community weight; tie-break max label
+        # hash.
+        best_w = jax.ops.segment_max(w, seg_src, num_segments=n)
+        with jax.named_scope("sweep.gather"):
+            is_best = (run_valid & (run_wgt >= best_w[seg_src])
+                       & (best_w[seg_src] > 0))
+        run_h = _label_hash(run_lab, jnp.asarray(iteration, jnp.int32))
+        best_h = jax.ops.segment_max(jnp.where(is_best, run_h, -1), seg_src,
+                                     num_segments=n)
+        with jax.named_scope("sweep.gather"):
+            pick = is_best & (run_h == best_h[seg_src])
+        best_lab = jax.ops.segment_min(jnp.where(pick, run_lab, bound),
+                                       seg_src, num_segments=n)
 
-    # Connecting weight to the *current* community (keep unless strictly worse).
-    to_cur = run_valid & (run_lab == labels[seg_src])
-    cur_w = jax.ops.segment_max(jnp.where(to_cur, run_wgt, _NEG), seg_src,
-                                num_segments=n)
+        # Connecting weight to the *current* community (keep unless
+        # strictly worse).
+        with jax.named_scope("sweep.gather"):
+            to_cur = run_valid & (run_lab == labels[seg_src])
+        cur_w = jax.ops.segment_max(jnp.where(to_cur, run_wgt, _NEG),
+                                    seg_src, num_segments=n)
 
-    adopt = active & (best_lab < bound) & (best_w > jnp.maximum(cur_w, 0.0))
-    new_labels = jnp.where(adopt, best_lab.astype(labels.dtype), labels)
-    changed = new_labels != labels
-    delta_n = jnp.sum(changed.astype(jnp.int32))
+        adopt = (active & (best_lab < bound)
+                 & (best_w > jnp.maximum(cur_w, 0.0)))
+        new_labels = jnp.where(adopt, best_lab.astype(labels.dtype), labels)
+    with jax.named_scope("sweep.wake"):
+        changed = new_labels != labels
+        delta_n = jnp.sum(changed.astype(jnp.int32))
     return new_labels, changed, delta_n
 
 
@@ -206,12 +239,14 @@ def lpa_run(graph: Graph, tau: float = 0.05, max_iterations: int = 20,
         labels, active = s.labels, s.active
         dn_total = jnp.int32(0)
         for sweep, klass in enumerate((~parity, parity)):
-            cand = active & klass
+            with jax.named_scope("sweep.wake"):
+                cand = active & klass
             labels, changed, dn = lpa_move(graph, labels, cand,
                                            2 * s.iteration + sweep)
             # pruning: processed vertices sleep; neighbors of changed wake up
-            active = (active & ~cand) | neighbors_of(graph, changed)
-            dn_total = dn_total + dn
+            with jax.named_scope("sweep.wake"):
+                active = (active & ~cand) | neighbors_of(graph, changed)
+                dn_total = dn_total + dn
             if profile:
                 row = 2 * s.iteration + sweep
                 buf = buf.at[row].set(jnp.stack(

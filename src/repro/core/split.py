@@ -53,24 +53,30 @@ def _min_label_sweep(graph: Graph, comm: jnp.ndarray, labels: jnp.ndarray,
     """
     n = graph.n
     bound = n if label_bound is None else label_bound
-    same = graph.edge_mask & (comm[graph.src] == comm[graph.dst])
-    # min over same-community neighbors; sentinel `bound` elsewhere
-    cand = jnp.where(same, labels[graph.dst], bound).astype(jnp.int32)
-    nbr_min = jax.ops.segment_min(cand, graph.src, num_segments=n)
-    new = jnp.minimum(labels, nbr_min.astype(labels.dtype))
-    if prune:
-        new = jnp.where(active, new, labels)
+    with jax.named_scope("sweep.gather"):
+        same = graph.edge_mask & (comm[graph.src] == comm[graph.dst])
+        # min over same-community neighbors; sentinel `bound` elsewhere
+        cand = jnp.where(same, labels[graph.dst], bound).astype(jnp.int32)
+    with jax.named_scope("sweep.reduce"):
+        nbr_min = jax.ops.segment_min(cand, graph.src, num_segments=n)
+        new = jnp.minimum(labels, nbr_min.astype(labels.dtype))
+        if prune:
+            new = jnp.where(active, new, labels)
     if shortcut:  # pointer jump (beyond-paper)
-        new = jnp.minimum(new, new[new if voffset is None else new + voffset])
-    changed = new != labels
-    delta_n = jnp.sum(changed.astype(jnp.int32))
-    if prune:
-        # reactivate same-community neighbors of changed vertices (line 20-21)
-        nxt_active = jax.ops.segment_max(
-            (changed[graph.dst] & same).astype(jnp.int32), graph.src,
-            num_segments=n) > 0
-    else:
-        nxt_active = active
+        with jax.named_scope("sweep.gather"):
+            new = jnp.minimum(
+                new, new[new if voffset is None else new + voffset])
+    with jax.named_scope("sweep.wake"):
+        changed = new != labels
+        delta_n = jnp.sum(changed.astype(jnp.int32))
+        if prune:
+            # reactivate same-community neighbors of changed vertices
+            # (lines 20-21)
+            nxt_active = jax.ops.segment_max(
+                (changed[graph.dst] & same).astype(jnp.int32), graph.src,
+                num_segments=n) > 0
+        else:
+            nxt_active = active
     return new, nxt_active, changed, delta_n
 
 
@@ -164,10 +170,11 @@ def min_label_wake(graph: Graph, comm: jnp.ndarray,
     slice's own edges are needed because the reactivation rule reads each
     vertex's *own* neighborhood.
     """
-    same = graph.edge_mask & (comm[graph.src] == comm[graph.dst])
-    return jax.ops.segment_max(
-        (changed[graph.dst] & same).astype(jnp.int32), graph.src,
-        num_segments=graph.n) > 0
+    with jax.named_scope("sweep.wake"):
+        same = graph.edge_mask & (comm[graph.src] == comm[graph.dst])
+        return jax.ops.segment_max(
+            (changed[graph.dst] & same).astype(jnp.int32), graph.src,
+            num_segments=graph.n) > 0
 
 
 def split_bfs_host(graph: Graph, comm: np.ndarray) -> np.ndarray:

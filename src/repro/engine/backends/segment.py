@@ -13,7 +13,6 @@ real vertex count so one executable serves the whole bucket.
 """
 from __future__ import annotations
 
-import time
 from types import SimpleNamespace
 
 import jax
@@ -35,6 +34,7 @@ from repro.engine.bucketing import (
 from repro.engine.cache import TRACE_LOG
 from repro.engine.config import EngineConfig
 from repro.engine.registry import BackendRun, BatchBackendRun, register_backend
+from repro.obs import span
 from repro.obs.convergence import batch_profiles, solo_profile
 
 
@@ -90,21 +90,20 @@ class SegmentBackend:
         active0 = jnp.asarray(pad_active(init_active, n_real, g.n))
 
         profiling = getattr(plan, "profile", False)
-        t0 = time.perf_counter()
-        out = plan.propagate(g, jnp.int32(n_real), labels0, active0)
-        state, pbuf = out if profiling else (out, None)
-        labels = jax.block_until_ready(state.labels)
-        lpa_iters = int(state.iteration)
-        t1 = time.perf_counter()
+        with span("engine.propagate") as prop_span:
+            out = plan.propagate(g, jnp.int32(n_real), labels0, active0)
+            state, pbuf = out if profiling else (out, None)
+            labels = jax.block_until_ready(state.labels)
+            lpa_iters = int(state.iteration)
 
         split_iters = 0
         sbuf = None
-        if plan.split is not None:
-            out = plan.split(g, labels, jnp.int32(n_real))
-            st, sbuf = out if plan.split_profile_rows else (out, None)
-            labels = jax.block_until_ready(st.labels)
-            split_iters = int(st.iterations)
-        t2 = time.perf_counter()
+        with span("engine.split") as split_span:
+            if plan.split is not None:
+                out = plan.split(g, labels, jnp.int32(n_real))
+                st, sbuf = out if plan.split_profile_rows else (out, None)
+                labels = jax.block_until_ready(st.labels)
+                split_iters = int(st.iterations)
 
         # profile fetch: one host transfer, after the convergence sync
         profile = solo_profile(pbuf, lpa_iters, sbuf, split_iters,
@@ -113,7 +112,9 @@ class SegmentBackend:
         return BackendRun(labels=np.asarray(labels),
                           lpa_iterations=lpa_iters,
                           split_iterations=split_iters,
-                          lpa_seconds=t1 - t0, split_seconds=t2 - t1,
+                          edge_slots=g.m_pad,
+                          lpa_seconds=prop_span.dur,
+                          split_seconds=split_span.dur,
                           profile=profile)
 
     # --- batched dispatch (GraphBatch disjoint-union packing) ---
@@ -323,22 +324,21 @@ class SegmentBackend:
         labels0, active0 = warm_state_rows(g.n, voffset,
                                            init_labels, init_active)
 
-        t0 = time.perf_counter()
-        out = plan.propagate(g, sizes, graph_id, voffset,
-                             jnp.asarray(labels0), jnp.asarray(active0))
-        (labels, iters, pbuf) = out if profiling else (*out, None)
-        labels = jax.block_until_ready(labels)
-        t1 = time.perf_counter()
+        with span("engine.propagate") as prop_span:
+            out = plan.propagate(g, sizes, graph_id, voffset,
+                                 jnp.asarray(labels0), jnp.asarray(active0))
+            (labels, iters, pbuf) = out if profiling else (*out, None)
+            labels = jax.block_until_ready(labels)
 
         split_iters = np.zeros(k1, np.int32)
         sbuf = None
-        if plan.split is not None:
-            out = plan.split(g, sizes, graph_id, voffset, labels)
-            (labels, siters, sbuf) = out if plan.split_profile_rows \
-                else (*out, None)
-            labels = jax.block_until_ready(labels)
-            split_iters = np.asarray(siters)
-        t2 = time.perf_counter()
+        with span("engine.split") as split_span:
+            if plan.split is not None:
+                out = plan.split(g, sizes, graph_id, voffset, labels)
+                (labels, siters, sbuf) = out if plan.split_profile_rows \
+                    else (*out, None)
+                labels = jax.block_until_ready(labels)
+                split_iters = np.asarray(siters)
 
         profiles = batch_profiles(pbuf, np.asarray(iters), sbuf,
                                   split_iters, plan.split_profile_rows,
@@ -346,5 +346,7 @@ class SegmentBackend:
         return BatchBackendRun(labels=np.asarray(labels),
                                lpa_iterations=np.asarray(iters),
                                split_iterations=split_iters,
-                               lpa_seconds=t1 - t0, split_seconds=t2 - t1,
+                               edge_slots=g.m_pad,
+                               lpa_seconds=prop_span.dur,
+                               split_seconds=split_span.dur,
                                profile=profiles)

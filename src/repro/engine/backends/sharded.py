@@ -14,7 +14,6 @@ pruning variant (the all-gather already dominates; see DESIGN.md §6).
 """
 from __future__ import annotations
 
-import time
 from functools import lru_cache
 from types import SimpleNamespace
 
@@ -38,6 +37,7 @@ from repro.engine.bucketing import (
 from repro.engine.cache import TRACE_LOG
 from repro.engine.config import EngineConfig
 from repro.engine.registry import BackendRun, register_backend
+from repro.obs import span
 
 
 @lru_cache(maxsize=1)
@@ -114,33 +114,35 @@ class ShardedBackend:
         threshold = int(np.float32(plan.tau) * np.float32(n_real))
         nr = jnp.int32(n_real)
 
-        t0 = time.perf_counter()
         it = 0
-        while it < plan.max_iterations:
-            labels, active, dn = plan.step(sg.nbr, sg.nw, sg.nmask, labels,
-                                           active, jnp.int32(it), nr)
-            it += 1
-            # host-driven convergence loop by design: one scalar readback
-            # lint: host-sync-ok — per exchange round (README "sharded")
-            if int(dn) <= threshold:
-                break
-        labels = jax.block_until_ready(labels)
-        t1 = time.perf_counter()
-
-        sit = 0
-        if plan.split is not None:
-            comm = labels
-            labels = jax.device_put(
-                jnp.arange(plan.rows, dtype=jnp.int32), rep)
-            while True:
-                labels, dn = plan.split(sg.nbr, sg.nw, sg.nmask, comm, labels)
-                sit += 1
-                # lint: host-sync-ok — split fixed-point, one scalar/round
-                if int(dn) == 0:
+        with span("engine.propagate") as prop_span:
+            while it < plan.max_iterations:
+                labels, active, dn = plan.step(sg.nbr, sg.nw, sg.nmask,
+                                               labels, active,
+                                               jnp.int32(it), nr)
+                it += 1
+                # host-driven convergence loop by design: one scalar readback
+                # lint: host-sync-ok — per exchange round (README "sharded")
+                if int(dn) <= threshold:
                     break
             labels = jax.block_until_ready(labels)
-        t2 = time.perf_counter()
+
+        sit = 0
+        with span("engine.split") as split_span:
+            if plan.split is not None:
+                comm = labels
+                labels = jax.device_put(
+                    jnp.arange(plan.rows, dtype=jnp.int32), rep)
+                while True:
+                    labels, dn = plan.split(sg.nbr, sg.nw, sg.nmask, comm,
+                                            labels)
+                    sit += 1
+                    # lint: host-sync-ok — split fixed-point, one scalar/round
+                    if int(dn) == 0:
+                        break
+                labels = jax.block_until_ready(labels)
 
         return BackendRun(labels=np.asarray(labels), lpa_iterations=it,
-                          split_iterations=sit,
-                          lpa_seconds=t1 - t0, split_seconds=t2 - t1)
+                          split_iterations=sit, edge_slots=sg.nbr.size,
+                          lpa_seconds=prop_span.dur,
+                          split_seconds=split_span.dur)

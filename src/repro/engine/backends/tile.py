@@ -25,7 +25,6 @@ contracts.
 """
 from __future__ import annotations
 
-import time
 from types import SimpleNamespace
 
 import jax
@@ -52,6 +51,7 @@ from repro.engine.registry import (
     tile_limit_error,
 )
 from repro.kernels import ops
+from repro.obs import span
 from repro.obs.convergence import batch_profiles, solo_profile
 
 
@@ -125,19 +125,25 @@ class TileBackend:
                 dn = jnp.int32(0)
                 for sweep in range(2):  # semi-synchronous parity sub-sweeps
                     klass = parity if sweep else ~parity
-                    cand = active & klass
+                    with jax.named_scope("sweep.wake"):
+                        cand = active & klass
                     seed = 2 * it + sweep
-                    best_lab, best_w, cur_w = ops.label_argmax(
-                        labels[nbr], nw, nmask, labels,
-                        jnp.asarray(seed, jnp.int32), mode=mode)
-                    adopt = cand & (best_w > jnp.maximum(cur_w, 0.0))
-                    new = jnp.where(adopt, best_lab.astype(jnp.int32), labels)
-                    changed = new != labels
-                    wake = jnp.any(changed[nbr] & nmask, axis=1)
-                    active = (active & ~cand) | (wake & real)
+                    with jax.named_scope("sweep.gather"):
+                        nbr_lab = labels[nbr]
+                    with jax.named_scope("sweep.reduce"):
+                        best_lab, best_w, cur_w = ops.label_argmax(
+                            nbr_lab, nw, nmask, labels,
+                            jnp.asarray(seed, jnp.int32), mode=mode)
+                        adopt = cand & (best_w > jnp.maximum(cur_w, 0.0))
+                        new = jnp.where(adopt, best_lab.astype(jnp.int32),
+                                        labels)
+                    with jax.named_scope("sweep.wake"):
+                        changed = new != labels
+                        wake = jnp.any(changed[nbr] & nmask, axis=1)
+                        active = (active & ~cand) | (wake & real)
+                        sc = jnp.sum(changed.astype(jnp.int32))
+                        dn = dn + sc
                     labels = new
-                    sc = jnp.sum(changed.astype(jnp.int32))
-                    dn = dn + sc
                     if profile:
                         buf = buf.at[seed].set(jnp.stack(
                             [jnp.sum(cand.astype(jnp.int32)), sc, seed]))
@@ -176,18 +182,22 @@ class TileBackend:
                 for sweep in range(2):  # semi-synchronous parity sub-sweeps
                     klass = parity if sweep else ~parity
                     seed = 2 * it + sweep
-                    new, active = ops.fused_move(
-                        labels[nbr], nw, nmask, chg[nbr], labels, active,
-                        candp, klass, real, jnp.asarray(seed, jnp.int32),
-                        mode=mode)
-                    chg = new != labels
-                    # candp is exactly this sub-sweep's candidate set
-                    # (refreshed-active & klass) — same counts as the
-                    # unfused body's `cand`.
-                    candp = active & klass
+                    with jax.named_scope("sweep.gather"):
+                        nbr_lab, nbr_chg = labels[nbr], chg[nbr]
+                    with jax.named_scope("sweep.reduce"):
+                        new, active = ops.fused_move(
+                            nbr_lab, nw, nmask, nbr_chg, labels, active,
+                            candp, klass, real, jnp.asarray(seed, jnp.int32),
+                            mode=mode)
+                    with jax.named_scope("sweep.wake"):
+                        chg = new != labels
+                        # candp is exactly this sub-sweep's candidate set
+                        # (refreshed-active & klass) — same counts as the
+                        # unfused body's `cand`.
+                        candp = active & klass
+                        sc = jnp.sum(chg.astype(jnp.int32))
+                        dn = dn + sc
                     labels = new
-                    sc = jnp.sum(chg.astype(jnp.int32))
-                    dn = dn + sc
                     if profile:
                         buf = buf.at[seed].set(jnp.stack(
                             [jnp.sum(candp.astype(jnp.int32)), sc, seed]))
@@ -208,7 +218,8 @@ class TileBackend:
 
         def _split(nbr, nmask, comm, labels0, n_real):
             TRACE_LOG.record("tile:split")
-            same = (comm[nbr] == comm[:, None]) & nmask
+            with jax.named_scope("sweep.gather"):
+                same = (comm[nbr] == comm[:, None]) & nmask
             real = jnp.asarray(ids) < n_real
 
             def cond(s):
@@ -218,21 +229,27 @@ class TileBackend:
             def body(s):
                 labels, active, it, _ = s[:4]
                 buf = s[4] if split_rows else None
-                new = ops.min_label(labels[nbr], comm[nbr], nmask, labels,
-                                    comm, mode=mode)
-                if prune:
-                    new = jnp.where(active, new, labels)
+                with jax.named_scope("sweep.gather"):
+                    nbr_lab, nbr_comm = labels[nbr], comm[nbr]
+                with jax.named_scope("sweep.reduce"):
+                    new = ops.min_label(nbr_lab, nbr_comm, nmask, labels,
+                                        comm, mode=mode)
+                    if prune:
+                        new = jnp.where(active, new, labels)
                 if shortcut:
-                    new = jnp.minimum(new, new[new])
-                changed = new != labels
-                dn = jnp.sum(changed.astype(jnp.int32))
+                    with jax.named_scope("sweep.gather"):
+                        new = jnp.minimum(new, new[new])
+                with jax.named_scope("sweep.wake"):
+                    changed = new != labels
+                    dn = jnp.sum(changed.astype(jnp.int32))
                 if split_rows:
                     row = jnp.minimum(it, split_rows - 1)
                     buf = buf.at[row].set(jnp.stack(
                         [jnp.sum((active & real).astype(jnp.int32)), dn,
                          it]))
                 if prune:
-                    active = jnp.any(changed[nbr] & same, axis=1)
+                    with jax.named_scope("sweep.wake"):
+                        active = jnp.any(changed[nbr] & same, axis=1)
                 nxt = (new, active, it + jnp.int32(1), dn)
                 return nxt + (buf,) if split_rows else nxt
 
@@ -259,13 +276,19 @@ class TileBackend:
                 # their own label, so the result matches active0 = ones).
                 labels, chg, it, _ = s[:4]
                 buf = s[4] if split_rows else None
-                new = ops.fused_split(labels[nbr], comm[nbr], nmask,
-                                      chg[nbr], labels, comm, prune=prune,
-                                      mode=mode)
+                with jax.named_scope("sweep.gather"):
+                    nbr_lab, nbr_comm, nbr_chg = (labels[nbr], comm[nbr],
+                                                  chg[nbr])
+                with jax.named_scope("sweep.reduce"):
+                    new = ops.fused_split(nbr_lab, nbr_comm, nmask, nbr_chg,
+                                          labels, comm, prune=prune,
+                                          mode=mode)
                 if shortcut:
-                    new = jnp.minimum(new, new[new])
-                changed = new != labels
-                dn = jnp.sum(changed.astype(jnp.int32))
+                    with jax.named_scope("sweep.gather"):
+                        new = jnp.minimum(new, new[new])
+                with jax.named_scope("sweep.wake"):
+                    changed = new != labels
+                    dn = jnp.sum(changed.astype(jnp.int32))
                 if split_rows:
                     # the fused body never materialises the prune
                     # worklist; the wake source (last sweep's changed
@@ -309,24 +332,24 @@ class TileBackend:
             else init_labels, n_real, plan.rows))
         active0 = jnp.asarray(pad_active(init_active, n_real, plan.rows))
 
-        t0 = time.perf_counter()
-        out = plan.propagate(nbr, nw, nmask, jnp.int32(n_real),
-                             labels0, active0)
-        (labels, it, pbuf) = out if profiling else (*out, None)
-        labels = jax.block_until_ready(labels)
-        lpa_iters = int(it)
-        t1 = time.perf_counter()
+        with span("engine.propagate") as prop_span:
+            out = plan.propagate(nbr, nw, nmask, jnp.int32(n_real),
+                                 labels0, active0)
+            (labels, it, pbuf) = out if profiling else (*out, None)
+            labels = jax.block_until_ready(labels)
+            lpa_iters = int(it)
 
         split_iters = 0
         sbuf = None
-        if plan.split is not None:
-            roots0 = jnp.arange(plan.rows, dtype=jnp.int32)
-            out = plan.split(nbr, nmask, labels, roots0, jnp.int32(n_real))
-            (labels, sit, sbuf) = out if plan.split_profile_rows \
-                else (*out, None)
-            labels = jax.block_until_ready(labels)
-            split_iters = int(sit)
-        t2 = time.perf_counter()
+        with span("engine.split") as split_span:
+            if plan.split is not None:
+                roots0 = jnp.arange(plan.rows, dtype=jnp.int32)
+                out = plan.split(nbr, nmask, labels, roots0,
+                                 jnp.int32(n_real))
+                (labels, sit, sbuf) = out if plan.split_profile_rows \
+                    else (*out, None)
+                labels = jax.block_until_ready(labels)
+                split_iters = int(sit)
 
         # profile fetch: one host transfer, after the convergence sync
         profile = solo_profile(pbuf, lpa_iters, sbuf, split_iters,
@@ -335,7 +358,9 @@ class TileBackend:
         return BackendRun(labels=np.asarray(labels),
                           lpa_iterations=lpa_iters,
                           split_iterations=split_iters,
-                          lpa_seconds=t1 - t0, split_seconds=t2 - t1,
+                          edge_slots=nbr.size,
+                          lpa_seconds=prop_span.dur,
+                          split_seconds=split_span.dur,
                           profile=profile)
 
     # --- out-of-core partition sweeps (repro.partition.ooc driver) ---
@@ -357,45 +382,59 @@ class TileBackend:
         def _move(nbr, nw, nmask, labels, cand, seed):
             TRACE_LOG.record("tile:part_move")
             row_lab = labels[: nbr.shape[0]]
-            best_lab, best_w, cur_w = ops.label_argmax(
-                labels[nbr], nw, nmask, row_lab, seed, mode=mode)
-            adopt = cand & (best_w > jnp.maximum(cur_w, 0.0))
-            return jnp.where(adopt, best_lab.astype(jnp.int32), row_lab)
+            with jax.named_scope("sweep.gather"):
+                nbr_lab = labels[nbr]
+            with jax.named_scope("sweep.reduce"):
+                best_lab, best_w, cur_w = ops.label_argmax(
+                    nbr_lab, nw, nmask, row_lab, seed, mode=mode)
+                adopt = cand & (best_w > jnp.maximum(cur_w, 0.0))
+                return jnp.where(adopt, best_lab.astype(jnp.int32), row_lab)
 
         def _wake(nbr, nmask, changed):
             TRACE_LOG.record("tile:part_wake")
-            return jnp.any(changed[nbr] & nmask, axis=1)
+            with jax.named_scope("sweep.wake"):
+                return jnp.any(changed[nbr] & nmask, axis=1)
 
         def _split(nbr, nmask, comm, labels, active):
             TRACE_LOG.record("tile:part_split")
             rows = nbr.shape[0]
-            new = ops.min_label(labels[nbr], comm[nbr], nmask,
-                                labels[:rows], comm[:rows], mode=mode)
-            if prune:
-                new = jnp.where(active, new, labels[:rows])
-            return new
+            with jax.named_scope("sweep.gather"):
+                nbr_lab, nbr_comm = labels[nbr], comm[nbr]
+            with jax.named_scope("sweep.reduce"):
+                new = ops.min_label(nbr_lab, nbr_comm, nmask,
+                                    labels[:rows], comm[:rows], mode=mode)
+                if prune:
+                    new = jnp.where(active, new, labels[:rows])
+                return new
 
         def _split_wake(nbr, nmask, comm, changed):
             TRACE_LOG.record("tile:part_split_wake")
             rows = nbr.shape[0]
-            same = (comm[nbr] == comm[:rows, None]) & nmask
-            return jnp.any(changed[nbr] & same, axis=1)
+            with jax.named_scope("sweep.wake"):
+                same = (comm[nbr] == comm[:rows, None]) & nmask
+                return jnp.any(changed[nbr] & same, axis=1)
 
         def _fused_move(nbr, nw, nmask, labels, chg, active, candp, klass,
                         seed):
             TRACE_LOG.record("tile:part_fused_move")
             rows = nbr.shape[0]
             real = jnp.ones(rows, dtype=bool)  # padded rows: nmask/klass off
-            return ops.fused_move(labels[nbr], nw, nmask, chg[nbr],
-                                  labels[:rows], active, candp, klass, real,
-                                  seed, mode=mode)
+            with jax.named_scope("sweep.gather"):
+                nbr_lab, nbr_chg = labels[nbr], chg[nbr]
+            with jax.named_scope("sweep.reduce"):
+                return ops.fused_move(nbr_lab, nw, nmask, nbr_chg,
+                                      labels[:rows], active, candp, klass,
+                                      real, seed, mode=mode)
 
         def _fused_split(nbr, nmask, comm, labels, chg):
             TRACE_LOG.record("tile:part_fused_split")
             rows = nbr.shape[0]
-            return ops.fused_split(labels[nbr], comm[nbr], nmask, chg[nbr],
-                                   labels[:rows], comm[:rows], prune=prune,
-                                   mode=mode)
+            with jax.named_scope("sweep.gather"):
+                nbr_lab, nbr_comm, nbr_chg = labels[nbr], comm[nbr], chg[nbr]
+            with jax.named_scope("sweep.reduce"):
+                return ops.fused_split(nbr_lab, nbr_comm, nmask, nbr_chg,
+                                       labels[:rows], comm[:rows],
+                                       prune=prune, mode=mode)
 
         return SimpleNamespace(
             move=jax.jit(_move), wake=jax.jit(_wake),
@@ -539,20 +578,26 @@ class TileBackend:
                 dn = jnp.zeros((k1,), jnp.int32)
                 for sweep in range(2):  # semi-synchronous parity sub-sweeps
                     klass = parity if sweep else ~parity
-                    cand = active & klass & running
+                    with jax.named_scope("sweep.wake"):
+                        cand = active & klass & running
                     seed = 2 * it + sweep
-                    best_lab, best_w, cur_w = ops.label_argmax(
-                        labels[nbr], nw, nmask, labels,
-                        jnp.asarray(seed, jnp.int32), mode=mode)
-                    adopt = cand & (best_w > jnp.maximum(cur_w, 0.0))
-                    new = jnp.where(adopt, best_lab.astype(jnp.int32), labels)
-                    changed = new != labels
-                    wake = jnp.any(changed[nbr] & nmask, axis=1)
-                    active = (active & ~cand) | (wake & real)
+                    with jax.named_scope("sweep.gather"):
+                        nbr_lab = labels[nbr]
+                    with jax.named_scope("sweep.reduce"):
+                        best_lab, best_w, cur_w = ops.label_argmax(
+                            nbr_lab, nw, nmask, labels,
+                            jnp.asarray(seed, jnp.int32), mode=mode)
+                        adopt = cand & (best_w > jnp.maximum(cur_w, 0.0))
+                        new = jnp.where(adopt, best_lab.astype(jnp.int32),
+                                        labels)
+                    with jax.named_scope("sweep.wake"):
+                        changed = new != labels
+                        wake = jnp.any(changed[nbr] & nmask, axis=1)
+                        active = (active & ~cand) | (wake & real)
+                        sc = jax.ops.segment_sum(changed.astype(jnp.int32),
+                                                 graph_id, num_segments=k1)
+                        dn = dn + sc
                     labels = new
-                    sc = jax.ops.segment_sum(changed.astype(jnp.int32),
-                                             graph_id, num_segments=k1)
-                    dn = dn + sc
                     if profile:
                         buf = buf.at[seed].set(jnp.stack(
                             [jax.ops.segment_sum(cand.astype(jnp.int32),
@@ -599,16 +644,20 @@ class TileBackend:
                 for sweep in range(2):  # semi-synchronous parity sub-sweeps
                     klass = parity if sweep else ~parity
                     seed = 2 * it + sweep
-                    new, active = ops.fused_move(
-                        labels[nbr], nw, nmask, chg[nbr], labels, active,
-                        candp, klass & running, real,
-                        jnp.asarray(seed, jnp.int32), mode=mode)
-                    chg = new != labels
-                    candp = active & klass & running
+                    with jax.named_scope("sweep.gather"):
+                        nbr_lab, nbr_chg = labels[nbr], chg[nbr]
+                    with jax.named_scope("sweep.reduce"):
+                        new, active = ops.fused_move(
+                            nbr_lab, nw, nmask, nbr_chg, labels, active,
+                            candp, klass & running, real,
+                            jnp.asarray(seed, jnp.int32), mode=mode)
+                    with jax.named_scope("sweep.wake"):
+                        chg = new != labels
+                        candp = active & klass & running
+                        sc = jax.ops.segment_sum(chg.astype(jnp.int32),
+                                                 graph_id, num_segments=k1)
+                        dn = dn + sc
                     labels = new
-                    sc = jax.ops.segment_sum(chg.astype(jnp.int32),
-                                             graph_id, num_segments=k1)
-                    dn = dn + sc
                     if profile:
                         # candp is exactly this sub-sweep's candidate set
                         buf = buf.at[seed].set(jnp.stack(
@@ -637,7 +686,8 @@ class TileBackend:
             TRACE_LOG.record("tile:batch_split")
             vid = jnp.asarray(ids)
             local = vid - voffset
-            same = (comm[nbr] == comm[:, None]) & nmask
+            with jax.named_scope("sweep.gather"):
+                same = (comm[nbr] == comm[:, None]) & nmask
             done0 = sizes == 0
 
             def cond(s):
@@ -647,15 +697,20 @@ class TileBackend:
             def body(s):
                 labels, active, done, iters = s[:4]
                 buf = s[4] if split_rows else None
-                new = ops.min_label(labels[nbr], comm[nbr], nmask, labels,
-                                    comm, mode=mode)
-                if prune:
-                    new = jnp.where(active, new, labels)
+                with jax.named_scope("sweep.gather"):
+                    nbr_lab, nbr_comm = labels[nbr], comm[nbr]
+                with jax.named_scope("sweep.reduce"):
+                    new = ops.min_label(nbr_lab, nbr_comm, nmask, labels,
+                                        comm, mode=mode)
+                    if prune:
+                        new = jnp.where(active, new, labels)
                 if shortcut:
-                    new = jnp.minimum(new, new[new + voffset])
-                changed = new != labels
-                dn = jax.ops.segment_sum(changed.astype(jnp.int32),
-                                         graph_id, num_segments=k1)
+                    with jax.named_scope("sweep.gather"):
+                        new = jnp.minimum(new, new[new + voffset])
+                with jax.named_scope("sweep.wake"):
+                    changed = new != labels
+                    dn = jax.ops.segment_sum(changed.astype(jnp.int32),
+                                             graph_id, num_segments=k1)
                 if split_rows:
                     # iters.max() is the global sweep index: a not-yet-done
                     # slot increments every sweep, so its count equals the
@@ -667,7 +722,8 @@ class TileBackend:
                                              graph_id, num_segments=k1),
                          dn]))
                 if prune:
-                    active = jnp.any(changed[nbr] & same, axis=1)
+                    with jax.named_scope("sweep.wake"):
+                        active = jnp.any(changed[nbr] & same, axis=1)
                 iters = iters + jnp.where(done, 0, 1)
                 nxt = (new, active, done | (dn == 0), iters)
                 return nxt + (buf,) if split_rows else nxt
@@ -696,14 +752,20 @@ class TileBackend:
             def body(s):
                 labels, chg, done, iters = s[:4]
                 buf = s[4] if split_rows else None
-                new = ops.fused_split(labels[nbr], comm[nbr], nmask,
-                                      chg[nbr], labels, comm, prune=prune,
-                                      mode=mode)
+                with jax.named_scope("sweep.gather"):
+                    nbr_lab, nbr_comm, nbr_chg = (labels[nbr], comm[nbr],
+                                                  chg[nbr])
+                with jax.named_scope("sweep.reduce"):
+                    new = ops.fused_split(nbr_lab, nbr_comm, nmask, nbr_chg,
+                                          labels, comm, prune=prune,
+                                          mode=mode)
                 if shortcut:
-                    new = jnp.minimum(new, new[new + voffset])
-                changed = new != labels
-                dn = jax.ops.segment_sum(changed.astype(jnp.int32),
-                                         graph_id, num_segments=k1)
+                    with jax.named_scope("sweep.gather"):
+                        new = jnp.minimum(new, new[new + voffset])
+                with jax.named_scope("sweep.wake"):
+                    changed = new != labels
+                    dn = jax.ops.segment_sum(changed.astype(jnp.int32),
+                                             graph_id, num_segments=k1)
                 if split_rows:
                     # Fused bodies fold the prune worklist into the kernel,
                     # so last sweep's changed set stands in as the frontier.
@@ -755,24 +817,24 @@ class TileBackend:
                                            init_labels, init_active)
         profiling = getattr(plan, "profile", False)
 
-        t0 = time.perf_counter()
-        out = plan.propagate(nbr, nw, nmask, sizes, graph_id,
-                             voffset, n_total,
-                             jnp.asarray(labels0),
-                             jnp.asarray(active0))
-        (labels, iters, pbuf) = out if profiling else (*out, None)
-        labels = jax.block_until_ready(labels)
-        t1 = time.perf_counter()
+        with span("engine.propagate") as prop_span:
+            out = plan.propagate(nbr, nw, nmask, sizes, graph_id,
+                                 voffset, n_total,
+                                 jnp.asarray(labels0),
+                                 jnp.asarray(active0))
+            (labels, iters, pbuf) = out if profiling else (*out, None)
+            labels = jax.block_until_ready(labels)
 
         split_iters = np.zeros(k1, np.int32)
         sbuf = None
-        if plan.split is not None:
-            out = plan.split(nbr, nmask, sizes, graph_id, voffset, labels)
-            (labels, siters, sbuf) = (out if plan.split_profile_rows
-                                      else (*out, None))
-            labels = jax.block_until_ready(labels)
-            split_iters = np.asarray(siters)
-        t2 = time.perf_counter()
+        with span("engine.split") as split_span:
+            if plan.split is not None:
+                out = plan.split(nbr, nmask, sizes, graph_id, voffset,
+                                 labels)
+                (labels, siters, sbuf) = (out if plan.split_profile_rows
+                                          else (*out, None))
+                labels = jax.block_until_ready(labels)
+                split_iters = np.asarray(siters)
 
         profiles = None
         if profiling:
@@ -784,5 +846,7 @@ class TileBackend:
         return BatchBackendRun(labels=np.asarray(labels),
                                lpa_iterations=np.asarray(iters),
                                split_iterations=split_iters,
-                               lpa_seconds=t1 - t0, split_seconds=t2 - t1,
+                               edge_slots=nbr.size,
+                               lpa_seconds=prop_span.dur,
+                               split_seconds=split_span.dur,
                                profile=profiles)

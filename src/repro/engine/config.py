@@ -172,7 +172,13 @@ class DetectionResult:
     backend: str                  # backend that actually ran
     lpa_iterations: int
     split_iterations: int         # 0 for split in ("none", "bfs_host")
-    timings: dict[str, float]     # phase -> seconds (propagation/split/...)
+    # Edge cells one gather pass of the sweep ran over: the padded edge
+    # bucket (segment) or the padded tiles' rows x d (tile, sharded); a
+    # batched member carries its dispatch's count; 0 out of core.
+    edge_slots: int
+    # phase -> seconds: the durations of the engine.prepare /
+    # engine.propagate / engine.split / engine.compact spans
+    timings: dict[str, float]
     bucket: tuple                 # (n, m, d) — or (k, n, m, d) when batched
     cache_hit: bool               # compiled plan came from the engine cache
     warm_started: bool            # fit started from caller/previous labels

@@ -29,7 +29,6 @@ tests/test_stream.py).
 from __future__ import annotations
 
 import threading
-import time
 from collections import OrderedDict
 
 import jax.numpy as jnp
@@ -290,19 +289,18 @@ class Engine:
                                   backend=backend, cache=self.cache,
                                   init_labels=init_labels,
                                   init_active=init_active)
-            t0 = time.perf_counter()
-            with span("engine.compact"):
+            with span("engine.compact") as compact:
                 labels, k = _compact_host(run.labels)
-            t_compact = time.perf_counter() - t0
 
         self._m_fits.inc()
         result = DetectionResult(
             labels=labels, num_communities=k, backend=run.backend,
             lpa_iterations=run.lpa_iterations,
             split_iterations=run.split_iterations,
+            edge_slots=0,  # each partition sweeps a window of its own
             timings={"prepare": run.plan_seconds,
                      "propagation": run.lpa_seconds,
-                     "split": run.split_seconds, "compact": t_compact},
+                     "split": run.split_seconds, "compact": compact.dur},
             bucket=(source.n, source.num_edges), cache_hit=run.cache_hit,
             warm_started=warm_started,
             partitions=run.num_partitions, ooc=run.stats(),
@@ -336,35 +334,33 @@ class Engine:
             plan, cache_hit = self.cache.get_or_build(
                 key, lambda: be.build(bucket, cfg))
 
-            t0 = time.perf_counter()
-            with span("engine.prepare"):
+            with span("engine.prepare") as prepare:
                 inputs = be.prepare(graph, bucket, cfg)
-            t_prep = time.perf_counter() - t0
 
+            # the backend times its phases as engine.propagate and
+            # engine.split inside this span
             with trace_context(name, bucket), span("engine.dispatch"):
                 run = be.run(plan, inputs, graph.n, init_labels,
                              init_active)
             labels = np.asarray(run.labels)[: graph.n]
 
-            t0 = time.perf_counter()
             split_seconds = run.split_seconds
             if cfg.split == "bfs_host":
-                with span("engine.split_host"):
+                with span("engine.split_host") as split_host:
                     labels = split_bfs_host(graph, labels)
-                split_seconds += time.perf_counter() - t0
+                split_seconds += split_host.dur
 
-            t0 = time.perf_counter()
-            with span("engine.compact"):
+            with span("engine.compact") as compact:
                 labels, k = _compact_host(labels)
-            t_compact = time.perf_counter() - t0
 
         self._m_fits.inc()
         result = DetectionResult(
             labels=labels, num_communities=k, backend=name,
             lpa_iterations=run.lpa_iterations,
             split_iterations=run.split_iterations,
-            timings={"prepare": t_prep, "propagation": run.lpa_seconds,
-                     "split": split_seconds, "compact": t_compact},
+            edge_slots=run.edge_slots,
+            timings={"prepare": prepare.dur, "propagation": run.lpa_seconds,
+                     "split": split_seconds, "compact": compact.dur},
             bucket=tuple(bucket), cache_hit=cache_hit,
             warm_started=warm_started,
             profile=run.profile,
@@ -500,8 +496,7 @@ class Engine:
                          name: str, be) -> list[DetectionResult]:
         cfg = self.config
         with span("engine.fit_many", backend=name, k=len(graphs)):
-            t0 = time.perf_counter()
-            with span("engine.prepare"):
+            with span("engine.prepare") as prepare:
                 batch = GraphBatch.pack(graphs)
                 bucket = batch_bucket_for(
                     batch, bucketing=cfg.bucketing,
@@ -517,7 +512,6 @@ class Engine:
                 # packing is a plain offset-sliced concatenation.
                 labels0 = batch.pack_labels(labels_r)
                 active0 = batch.pack_active(active_r)
-            t_prep = time.perf_counter() - t0
 
             with trace_context(name, ("batch", *bucket)), \
                     span("engine.dispatch"):
@@ -542,24 +536,24 @@ class Engine:
                 labels = labels_all[lo:hi]
                 w = float(weights[i])
 
-                t0 = time.perf_counter()
                 split_host = 0.0
                 if cfg.split == "bfs_host":
-                    labels = split_bfs_host(graph, labels)
-                    split_host = time.perf_counter() - t0
+                    with span("engine.split_host") as host_split:
+                        labels = split_bfs_host(graph, labels)
+                    split_host = host_split.dur
 
-                t0 = time.perf_counter()
-                labels, k = _compact_host(labels)
-                t_compact = time.perf_counter() - t0
+                with span("engine.compact") as compact:
+                    labels, k = _compact_host(labels)
 
                 result = DetectionResult(
                     labels=labels, num_communities=k, backend=name,
                     lpa_iterations=int(run.lpa_iterations[i]),
                     split_iterations=int(run.split_iterations[i]),
-                    timings={"prorated_prepare": t_prep * w,
+                    edge_slots=run.edge_slots,
+                    timings={"prorated_prepare": prepare.dur * w,
                              "prorated_propagation": run.lpa_seconds * w,
                              "prorated_split": run.split_seconds * w,
-                             "split": split_host, "compact": t_compact},
+                             "split": split_host, "compact": compact.dur},
                     bucket=tuple(bucket), cache_hit=cache_hit,
                     warm_started=warm_r[i],
                     batch_size=len(graphs), batch_index=i,

@@ -46,10 +46,18 @@ from repro.kernels.tiling import MAX_TILE_DEGREE
 
 
 class BackendRun(NamedTuple):
-    """Raw backend output (labels still padded + uncompacted)."""
+    """Raw backend output (labels still padded + uncompacted).
+
+    ``lpa_seconds`` / ``split_seconds`` are the durations of the
+    ``engine.propagate`` / ``engine.split`` spans around the two phases.
+    ``edge_slots`` is the number of edge cells one gather pass of the
+    sweep runs over: the padded edge bucket (segment) or the padded
+    tiles' rows x d (tile, sharded), read from shapes the plan holds.
+    """
     labels: np.ndarray        # (bucket rows,) int32 — engine slices [:n_real]
     lpa_iterations: int
     split_iterations: int
+    edge_slots: int
     lpa_seconds: float
     split_seconds: float
     # ConvergenceProfile when the plan was built with profiling on
@@ -62,6 +70,7 @@ class BatchBackendRun(NamedTuple):
     labels: np.ndarray            # (bucket rows,) int32 local labels
     lpa_iterations: np.ndarray    # (k_bucket + 1,) int32 per slot
     split_iterations: np.ndarray  # (k_bucket + 1,) int32 per slot
+    edge_slots: int               # of the packed dispatch (see BackendRun)
     lpa_seconds: float
     split_seconds: float
     # per-slot list of ConvergenceProfile under profiling; None otherwise.

@@ -88,6 +88,7 @@ def fused_move_pallas(nbr_lab: jnp.ndarray, nbr_w: jnp.ndarray,
 
     new, act = pl.pallas_call(
         _fused_move_kernel,
+        name="fused_move",
         grid=grid,
         in_specs=[seed_spec, row_spec, row_spec, row_spec, row_spec,
                   col_spec, col_spec, col_spec, col_spec, col_spec],
@@ -158,6 +159,7 @@ def fused_split_pallas(nbr_lab: jnp.ndarray, nbr_comm: jnp.ndarray,
                     col(self_lab), col(self_comm))
     out = pl.pallas_call(
         kernel,
+        name="fused_split",
         grid=grid,
         in_specs=in_specs,
         out_specs=col_spec,

@@ -99,6 +99,7 @@ def label_argmax_pallas(nbr_lab: jnp.ndarray, nbr_w: jnp.ndarray,
     )
     best_lab, best_w, cur_w = pl.pallas_call(
         _label_argmax_kernel,
+        name="label_argmax",
         grid=grid,
         in_specs=[seed_spec, row_spec, row_spec, row_spec, col_spec],
         out_specs=(col_spec, col_spec, col_spec),

@@ -36,6 +36,7 @@ def min_label_pallas(nbr_lab: jnp.ndarray, nbr_comm: jnp.ndarray,
     col_spec = pl.BlockSpec((tile_b, 1), lambda i: (i, 0))
     out = pl.pallas_call(
         _min_label_kernel,
+        name="min_label",
         grid=grid,
         in_specs=[row_spec, row_spec, row_spec, col_spec, col_spec],
         out_specs=col_spec,

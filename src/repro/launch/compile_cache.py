@@ -21,10 +21,17 @@ def configure_compile_cache() -> str:
     ``<checkout>/.jax_cache``: a fixed path, never one built from a temp
     name, a pid or the time, so a later run finds what an earlier one
     compiled.  Returns the directory in use.
+
+    The cache key includes the programs' op metadata: an executable keeps
+    the ``op_name`` metadata it was compiled with, and profiles read the
+    ``sweep.*`` scopes from it, so a program must not load the executable
+    of an equal program compiled from other source (another checkout's,
+    or one without the scopes).
     """
+    import jax
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env
-    import jax
     jax.config.update("jax_compilation_cache_dir", str(DEFAULT_CACHE_DIR))
     return str(DEFAULT_CACHE_DIR)

@@ -4,9 +4,20 @@ The same attribution idea as ``trace_context`` in ``engine/cache.py`` —
 a ContextVar carries the current span so nested stages parent correctly
 across threads and concurrent engines — but recording *durations*
 instead of retrace counts.  Spans wrap host-side stage boundaries only
-(engine prepare/dispatch/compact, ooc partition visits / prefetch / halo
-exchange, serving admission→dispatch→settle); they never enter jitted or
-per-sweep code, which the R006 lint rule enforces.
+(engine prepare/propagate/split/compact, ooc partition visits / prefetch
+/ halo exchange, serving admission→dispatch→settle); they never enter
+jitted or per-sweep code, which the R006 lint rule enforces.  Inside
+traced code the device-side scope is ``jax.named_scope`` (the
+``sweep.*`` scopes of the sweep bodies), which names operations in the
+compiled program at no run-time cost.
+
+A span always measures its duration (``Span.dur``, the one host clock
+for a stage: the engine's ``DetectionResult.timings`` are span
+durations).  Only recording depends on ``Tracer.enabled``: an enabled
+span is kept in the bounded history and also opens a
+``jax.profiler.TraceAnnotation`` of the same bare name, so every stage
+shows on the host line of a ``jax.profiler`` trace, on the same clock as
+the device operations it launched.
 
 Export is the Chrome trace-event JSON array (``chrome://tracing`` /
 Perfetto): complete events (``"ph": "X"``) with microsecond timestamps
@@ -25,6 +36,8 @@ import threading
 import time
 from collections import deque
 from typing import Any
+
+from jax.profiler import TraceAnnotation
 
 _MAX_SPANS = 65536  # bounded history: long servers drop oldest spans
 
@@ -51,17 +64,6 @@ class Span:
         return self
 
 
-class _NullSpan:
-    """Returned when tracing is disabled — absorbs ``.set()`` for free."""
-    __slots__ = ()
-
-    def set(self, **attrs):
-        return self
-
-
-_NULL = _NullSpan()
-
-
 class Tracer:
     """Bounded in-memory span recorder with a Chrome-trace exporter."""
 
@@ -73,8 +75,14 @@ class Tracer:
 
     @contextlib.contextmanager
     def span(self, name: str, **attrs):
+        """Time the block; when enabled, also record it and annotate the
+        profiler trace.  ``Span.dur`` is set on exit either way."""
         if not self.enabled:
-            yield _NULL
+            s = Span(name=name, t0=time.perf_counter(), attrs=attrs)
+            try:
+                yield s
+            finally:
+                s.dur = time.perf_counter() - s.t0
             return
         parent = _CURRENT.get()
         s = Span(name=name, t0=time.perf_counter(), span_id=next(_ids),
@@ -82,7 +90,8 @@ class Tracer:
                  tid=threading.get_ident(), attrs=dict(attrs))
         token = _CURRENT.set(s)
         try:
-            yield s
+            with TraceAnnotation(name):
+                yield s
         finally:
             _CURRENT.reset(token)
             s.dur = time.perf_counter() - s.t0
@@ -129,6 +138,6 @@ class Tracer:
 
 # Process-global tracer.  ``span("engine.fit")`` is the one-liner every
 # stage boundary uses; disable with ``TRACER.enabled = False`` (spans
-# then cost one attribute read and an empty yield).
+# then only time the block: no history, no profiler annotation).
 TRACER = Tracer()
 span = TRACER.span

@@ -49,3 +49,37 @@ def test_default_is_fixed_path_in_checkout():
     out = _run(None, compile_=False)
     assert out["USED"] == out["CONFIG"] == out["DEFAULT"] \
         == str(REPO / ".jax_cache")
+
+
+_SCOPED = r"""
+import jax, jax.numpy as jnp
+from repro.launch.compile_cache import configure_compile_cache
+configure_compile_cache()
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+def step(x):
+    if {scoped}:
+        with jax.named_scope("sweep.gather"):
+            return jnp.sin(x) * 3.0
+    return jnp.sin(x) * 3.0
+text = jax.jit(step).lower(jnp.ones(17)).compile().as_text()
+print("SCOPED", "sweep.gather" in text)
+"""
+
+
+def test_cached_executable_keeps_the_scopes_it_was_compiled_with(tmp_path):
+    """A program that differs from a cached one only in its named scopes
+    compiles its own executable: profiles read the scopes from the
+    executable's op metadata."""
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env.update(JAX_PLATFORMS="cpu", PYTHONPATH=str(REPO / "src"),
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "jaxcache"))
+    seen = []
+    for scoped in (False, True):
+        proc = subprocess.run([sys.executable, "-c",
+                               _SCOPED.format(scoped=scoped)],
+                              env=env, capture_output=True, text=True,
+                              timeout=300)
+        assert proc.returncode == 0, proc.stderr[-2000:]
+        seen.append(proc.stdout.split("SCOPED ", 1)[1].strip())
+    assert seen == ["False", "True"]

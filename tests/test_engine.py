@@ -262,3 +262,65 @@ def test_forced_tile_refuses_unadmitted_graph():
     assert TRACE_LOG.snapshot() == before
     # auto falls back to segment and still answers
     assert fresh_engine().fit(star).backend == "segment"
+
+
+@pytest.mark.parametrize("backend", BACKENDS)
+def test_edge_slots_are_the_padded_layout(backend):
+    """edge_slots is the padded edge bucket on segment and the padded
+    tiles' rows x d on tile and sharded, solo and batched."""
+    import jax
+
+    from repro.engine.backends.sharded import _shard_rows
+    from repro.engine.bucketing import tile_rows
+    rows = {"segment": None, "tile": tile_rows,
+            "sharded": lambda n: _shard_rows(n, jax.device_count())}[backend]
+    g = erdos_renyi(100, 4.0, seed=8)
+    eng = fresh_engine(backend=backend)
+    r = eng.fit(g)
+    n, m, d = r.bucket
+    assert r.edge_slots == (m if rows is None else rows(n) * d)
+    assert 0 < g.num_edges <= r.edge_slots
+    if backend != "sharded":
+        members = eng.fit_many([g, karate_club()[0]])
+        _, n_b, m_b, d_b = members[0].bucket
+        want = m_b if rows is None else rows(n_b) * d_b
+        assert [x.edge_slots for x in members] == [want, want]
+
+
+def _sweep_programs(backend, fuse):
+    """The compiled text of a backend's propagate and split programs."""
+    from repro.core.graph import to_padded_neighbors
+    from repro.engine.bucketing import bucket_for, pad_graph, tile_rows
+    from repro.engine.registry import get_backend
+    g = erdos_renyi(100, 4.0, seed=8)
+    cfg = EngineConfig(fuse_sweeps=fuse)
+    bucket = bucket_for(g)
+    plan = get_backend(backend).build(bucket, cfg)
+    n_real = jnp.int32(g.n)
+    if backend == "segment":
+        gp = pad_graph(g, bucket)
+        labels = jnp.arange(gp.n, dtype=jnp.int32)
+        ones = jnp.ones(gp.n, bool)
+        return (plan.propagate.lower(gp, n_real, labels, ones),
+                plan.split.lower(gp, labels, n_real))
+    rows = tile_rows(bucket.n)
+    nbr, nw, nmask = (jnp.asarray(x) for x in to_padded_neighbors(
+        pad_graph(g, bucket), d_max=bucket.d))
+    labels = jnp.arange(rows, dtype=jnp.int32)
+    return (plan.propagate.lower(nbr, nw, nmask, n_real, labels,
+                                 jnp.ones(rows, bool)),
+            plan.split.lower(nbr, nmask, labels, labels, n_real))
+
+
+@pytest.mark.parametrize("backend,fuse", [("segment", "auto"),
+                                          ("tile", "off"), ("tile", "on")])
+def test_sweep_programs_carry_named_scopes(backend, fuse):
+    """Each sweep program names its gather, reduce and wake operations
+    (and segment's propagation its sort) in the compiled program's
+    metadata."""
+    scopes = ("sweep.gather", "sweep.sort", "sweep.reduce", "sweep.wake")
+    propagate, split = (lowered.compile().as_text()
+                        for lowered in _sweep_programs(backend, fuse))
+    for text, sort in ((propagate, backend == "segment"), (split, False)):
+        found = {s for s in scopes if f"/{s}/" in text}
+        assert found == set(scopes) - (set() if sort else {"sweep.sort"})

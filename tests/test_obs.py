@@ -13,6 +13,7 @@ that now read through the registry.
 """
 import json
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -164,12 +165,98 @@ def test_tracer_disabled_is_free():
     assert tr.spans() == []
 
 
+def test_disabled_span_still_times_the_block():
+    tr = Tracer(enabled=False)
+    with tr.span("x") as s:
+        time.sleep(0.01)
+    assert s.dur >= 0.01
+    assert tr.spans() == [] and tr.current() is None
+
+
 def test_engine_fit_emits_spans():
     g = karate_club()[0]
     TRACER.reset()
     fresh_engine().fit(g)
     names = {s.name for s in TRACER.spans("engine.")}
     assert {"engine.fit", "engine.prepare", "engine.dispatch"} <= names
+
+
+STAGES = ("engine.prepare", "engine.propagate", "engine.split",
+          "engine.compact")
+
+
+def _profiled_fit(tmp_path, backend):
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    g = erdos_renyi(300, 5.0, seed=5)
+    eng = fresh_engine(backend=backend)
+    eng.fit(g)  # compile outside the trace
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        eng.fit(g)
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                         / "*.xplane.pb"))[0]
+    return [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host")
+            for line in plane.lines for ev in line.events
+            if ev.name.startswith("engine.")]
+
+
+@pytest.mark.parametrize("backend", BACKENDS)
+def test_engine_spans_in_profiler_trace(tmp_path, backend):
+    """Each engine stage is a host annotation of the profiler's trace,
+    nested inside engine.fit, on the clock of the device operations."""
+    events = _profiled_fit(tmp_path, backend)
+    fits = [e for e in events if e[0] == "engine.fit"]
+    assert len(fits) == 1
+    _, f0, f1 = fits[0]
+    for stage in STAGES:
+        inside = [e for e in events if e[0] == stage]
+        assert len(inside) == 1, stage
+        assert f0 <= inside[0][1] <= inside[0][2] <= f1, stage
+    starts = [next(e[1] for e in events if e[0] == s) for s in STAGES]
+    assert starts == sorted(starts)
+
+
+def _record_spans(monkeypatch, modules):
+    """Wrap each module's ``span`` to keep the spans it yields."""
+    import contextlib
+    seen = []
+    for mod in modules:
+        real = mod.span
+
+        @contextlib.contextmanager
+        def keep(name, real=real, **attrs):
+            with real(name, **attrs) as s:
+                yield s
+            seen.append(s)
+        monkeypatch.setattr(mod, "span", keep)
+    return seen
+
+
+@pytest.mark.parametrize("enabled", [True, False], ids=["on", "off"])
+@pytest.mark.parametrize("backend", BACKENDS)
+def test_timings_are_span_durations(monkeypatch, backend, enabled):
+    import repro.engine.backends.segment as seg_mod
+    import repro.engine.backends.tile as tile_mod
+    import repro.engine.engine as engine_mod
+    seen = _record_spans(monkeypatch, (engine_mod, seg_mod, tile_mod))
+    monkeypatch.setattr(TRACER, "enabled", enabled)
+    TRACER.reset()
+    r = fresh_engine(backend=backend).fit(erdos_renyi(200, 5.0, seed=2))
+    dur = {s.name: s.dur for s in seen}
+    assert r.timings == {"prepare": dur["engine.prepare"],
+                         "propagation": dur["engine.propagate"],
+                         "split": dur["engine.split"],
+                         "compact": dur["engine.compact"]}
+    assert all(v > 0 for v in r.timings.values())
+    recorded = {s.name for s in TRACER.spans("engine.")}
+    assert recorded == (set(dur) if enabled else set())
 
 
 # --- convergence profiles: bit parity ---

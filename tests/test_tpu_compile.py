@@ -117,3 +117,36 @@ def test_largest_admitted_tile_plan_fits_hbm(one_chip, n_bucket, d):
         total = (ma.argument_size_in_bytes + ma.temp_size_in_bytes
                  + ma.output_size_in_bytes)
         assert total <= V5E_HBM_BYTES // 2, (name, total)
+
+
+def test_tile_programs_name_kernels_and_sweep_scopes(one_chip):
+    """The fused tile programs compiled for a v5e keep the kernel op names
+    the trace readers match (``fused_move``, ``fused_split`` and their
+    numbered copies) and carry the sweep scopes in their metadata."""
+    import re
+    plan = get_backend("tile").build(
+        BucketKey(1024, 4096, 128),
+        EngineConfig(kernel_mode="pallas", fuse_sweeps="on"))
+    r, d = plan.rows, 128
+    tiles = (_spec(one_chip, (r, d), jnp.int32),
+             _spec(one_chip, (r, d), jnp.float32),
+             _spec(one_chip, (r, d), jnp.bool_))
+    col = lambda dt: _spec(one_chip, (r,), dt)  # noqa: E731
+    n_real = _spec(one_chip, (), jnp.int32)
+    programs = {
+        "fused_move": plan.propagate.lower(*tiles, n_real, col(jnp.int32),
+                                           col(jnp.bool_)),
+        "fused_split": plan.split.lower(tiles[0], tiles[2], col(jnp.int32),
+                                        col(jnp.int32), n_real),
+    }
+    for kernel, lowered in programs.items():
+        text = lowered.compile().as_text()
+        calls = [line for line in text.splitlines()
+                 if "tpu_custom_call" in line and " = " in line]
+        assert calls, kernel
+        for line in calls:
+            name = line.split(" = ")[0].replace("ROOT", "").strip()
+            assert re.fullmatch(rf"%{kernel}(\.\d+)?", name), name
+            assert "/sweep.reduce/" in line, name
+        for scope in ("sweep.gather", "sweep.wake"):
+            assert f"/{scope}/" in text, (kernel, scope)
