@@ -20,10 +20,32 @@ import jax.numpy as jnp
 import numpy as np
 
 _LANE = 128  # TPU lane alignment for padded edge arrays.
+_SUBLANE = 8  # TPU sublane count: tile rows and the narrowest tile width.
 
 
 def _round_up(x: int, mult: int) -> int:
     return ((x + mult - 1) // mult) * mult
+
+
+def next_pow2(x: int, floor: int = 1) -> int:
+    return max(int(floor), 1 << max(int(x) - 1, 0).bit_length())
+
+
+def tile_width(d_real: int, *, exact: bool = False) -> int:
+    """Width of a padded neighbor-tile row for maximum degree ``d_real``.
+
+    Below 128 the row is the next power of two of the degree, at least 8
+    (the sublane count, so a row tile's (B, 8, 8) equality cube is one
+    vreg row group): a degree-4 road graph gathers over 8 slots a row,
+    not 128.  From 128 up the row is lane-rounded: the next power of two
+    rounded up to 128, or, when ``exact``, the degree itself rounded up
+    to 128.
+    """
+    d_real = max(int(d_real), 1)
+    pow2 = next_pow2(d_real)
+    if pow2 < _LANE:
+        return max(pow2, _SUBLANE)
+    return _round_up(d_real if exact else pow2, _LANE)
 
 
 @partial(jax.tree_util.register_dataclass,
@@ -169,11 +191,12 @@ def to_padded_neighbors(graph: Graph, d_max: int | None = None,
     """Dense padded neighbor matrices for the Pallas tile path.
 
     Returns (nbr, nw, nmask) with shapes (n_pad, d_max): neighbor vertex ids,
-    weights, and validity.  ``n_pad`` rounds n up to 8 (sublane), ``d_max``
-    rounds the max degree up to 128 (lane).  Pad neighbor ids point at the row
-    vertex itself with weight 0 (self edges are excluded by construction, so a
-    0-weight self slot can never win the argmax).  A ``d_max`` below the
-    maximum degree raises: dropping edges would change the answer.
+    weights, and validity.  ``n_pad`` rounds n up to 8 (sublane); ``d_max``
+    is the row width as given, by default ``tile_width`` of the maximum
+    degree.  Pad neighbor ids point at the row vertex itself with weight 0
+    (self edges are excluded by construction, so a 0-weight self slot can
+    never win the argmax).  A ``d_max`` below the maximum degree raises:
+    dropping edges would change the answer.
     """
     row_ptr = np.asarray(graph.row_ptr).astype(np.int64)
     dst = np.asarray(graph.dst)[: graph.num_edges]
@@ -181,12 +204,11 @@ def to_padded_neighbors(graph: Graph, d_max: int | None = None,
     deg = row_ptr[1:] - row_ptr[:-1]
     d_real = int(deg.max()) if len(deg) else 0
     if d_max is None:
-        d_max = max(d_real, 1)
+        d_max = tile_width(d_real)
     if d_max < d_real:
         raise ValueError(f"d_max={d_max} is below the graph's maximum "
                          f"degree {d_real}; its edges would be dropped")
-    d_max = _round_up(d_max, _LANE)
-    n_pad = _round_up(graph.n, 8)
+    n_pad = _round_up(graph.n, _SUBLANE)
 
     nbr = np.repeat(np.arange(n_pad, dtype=np.int32)[:, None], d_max, axis=1)
     nw = np.zeros((n_pad, d_max), dtype=np.float32)

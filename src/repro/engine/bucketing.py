@@ -17,14 +17,14 @@ from typing import NamedTuple
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.graph import Graph, _LANE, _round_up
+from repro.core.graph import Graph, _round_up, next_pow2, tile_width
 
 
 class BucketKey(NamedTuple):
     """Canonical padded shapes — the compile-cache key's shape component."""
     n: int   # vertex bucket (>= real n)
     m: int   # directed-edge bucket (>= real m_pad; multiple of 128)
-    d: int   # max-degree bucket (multiple of 128; tile/sharded backends)
+    d: int   # tile row width, ``tile_width`` of the max degree (tile/sharded)
 
 
 class BatchBucketKey(NamedTuple):
@@ -37,11 +37,7 @@ class BatchBucketKey(NamedTuple):
     k: int   # graph-count bucket (>= real batch size)
     n: int   # total-vertex bucket (>= packed n)
     m: int   # total-edge bucket (>= packed m_pad; multiple of 128)
-    d: int   # max-degree bucket across members (multiple of 128)
-
-
-def next_pow2(x: int, floor: int = 1) -> int:
-    return max(int(floor), 1 << max(int(x) - 1, 0).bit_length())
+    d: int   # tile row width for the max member degree (``tile_width``)
 
 
 def max_degree(graph: Graph) -> int:
@@ -56,13 +52,12 @@ def tile_rows(bucket_n: int) -> int:
 
 def vertex_degree_bucket(n: int, d_real: int, *, bucketing: str = "pow2",
                          min_vertex_bucket: int = 256) -> tuple[int, int]:
-    """(vertex bucket, lane-rounded degree bucket) for ``n`` vertices of
-    maximum degree ``d_real`` — the tile shapes a plan compiles at."""
-    d_real = max(d_real, 1)
+    """(vertex bucket, tile row width) for ``n`` vertices of maximum
+    degree ``d_real`` — the tile shapes a plan compiles at."""
+    d = tile_width(d_real, exact=bucketing == "exact")
     if bucketing == "exact":
-        return n, _round_up(d_real, _LANE)
-    return (next_pow2(n, min_vertex_bucket),
-            _round_up(next_pow2(d_real), _LANE))
+        return n, d
+    return next_pow2(n, min_vertex_bucket), d
 
 
 def bucket_for(graph: Graph, *, bucketing: str = "pow2",

@@ -33,7 +33,7 @@ from typing import NamedTuple, Protocol
 import jax
 import numpy as np
 
-from repro.core.graph import Graph
+from repro.core.graph import _LANE, Graph
 from repro.engine.bucketing import (
     BatchBucketKey,
     BucketKey,
@@ -127,8 +127,10 @@ def backend_names() -> tuple[str, ...]:
 
 # Auto-selection: the tile path materialises (rows, d) dense neighbor
 # tiles — a win on TPU for degree-bounded graphs, a memory loss on skewed
-# ones.  Both limits apply to the shapes a tile plan compiles at: bucket
-# rows by the lane-rounded degree bucket.  Documented in README.md.
+# ones.  Both limits apply to the shapes a tile plan compiles at; cells
+# are counted at bucket rows by the tile width lane-rounded to 128, since
+# a narrower (rows, d) array may be lane-padded in HBM.  Documented in
+# README.md.
 # From compiled.memory_analysis() for one TPU v5e (15.75 GB usable): the
 # fused tile propagate program at 2^27 cells (2^20 rows x 128) holds
 # 1.21 GB of arguments and 5.91 GB of temporaries; at 2^28 cells it needs
@@ -142,11 +144,12 @@ def tile_limit_error(n_bucket: int, d_bucket: int) -> str | None:
     if d_bucket > MAX_TILE_DEGREE:
         return (f"degree bucket {d_bucket} exceeds {MAX_TILE_DEGREE}, the "
                 f"widest tile row the kernels compile for")
-    cells = tile_rows(n_bucket) * d_bucket
+    lanes = max(d_bucket, _LANE)
+    cells = tile_rows(n_bucket) * lanes
     if cells > _TILE_MAX_CELLS:
         return (f"{cells} tile cells ({tile_rows(n_bucket)} rows x "
-                f"{d_bucket}) exceed the {_TILE_MAX_CELLS}-cell limit that "
-                f"fits one TPU v5e's HBM")
+                f"{lanes} lanes) exceed the {_TILE_MAX_CELLS}-cell limit "
+                f"that fits one TPU v5e's HBM")
     return None
 
 

@@ -173,15 +173,11 @@ def _host_threshold(n: int, tau: float, backend: str,
 
 
 def _shapes_for(plan: PartitionPlan, bucketing: str) -> PartitionShapes:
-    from repro.core.graph import _LANE, _round_up
-    from repro.engine.bucketing import next_pow2
+    from repro.core.graph import _LANE, _round_up, next_pow2, tile_width
     rows = next_pow2(plan.max_part_size, 8)
     n_loc = max(next_pow2(plan.max_n_local, 8), rows)
     m = max(_round_up(next_pow2(plan.max_part_edges), _LANE), _LANE)
-    if bucketing == "exact":
-        d = _round_up(plan.d_max, _LANE)
-    else:
-        d = _round_up(next_pow2(plan.d_max), _LANE)
+    d = tile_width(plan.d_max, exact=bucketing == "exact")
     return PartitionShapes(n_loc=n_loc, m=m, rows=rows, d=d)
 
 
@@ -232,11 +228,10 @@ def fit_out_of_core(source, config: EngineConfig | None = None, *,
     row_ptr = np.asarray(source.row_ptr())
     n = int(source.n)
 
-    from repro.core.graph import _LANE, _round_up
-    from repro.engine.bucketing import next_pow2
+    from repro.core.graph import tile_width
     degrees = row_ptr[1:] - row_ptr[:-1]
     d_real = int(degrees.max()) if n else 1
-    d_bucket = _round_up(next_pow2(max(d_real, 1)), _LANE)
+    d_bucket = tile_width(d_real)
 
     name = backend or cfg.backend
     if name == "auto":

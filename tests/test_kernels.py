@@ -29,8 +29,13 @@ def _tiles(n, d, seed, n_labels=None, wdtype=np.float32):
         jnp.asarray(cur)
 
 
+# tile widths below 128 follow the graph's degree (core.graph.tile_width)
+NARROW = [(8, 8), (256, 8), (48, 16), (64, 64)]
+
+
 @pytest.mark.parametrize("shape", [(8, 128), (16, 128), (8, 256),
-                                   (40, 128), (64, 512), (128, 384)])
+                                   (40, 128), (64, 512), (128, 384)]
+                         + NARROW)
 @pytest.mark.parametrize("seed", [0, 3])
 def test_label_argmax_shape_sweep(shape, seed):
     lab, w, mask, cur = _tiles(*shape, seed=seed)
@@ -42,7 +47,7 @@ def test_label_argmax_shape_sweep(shape, seed):
                                        rtol=1e-6)
 
 
-@pytest.mark.parametrize("shape", [(8, 128), (48, 256), (16, 640)])
+@pytest.mark.parametrize("shape", [(8, 128), (48, 256), (16, 640)] + NARROW)
 def test_min_label_shape_sweep(shape, seed=1):
     n, d = shape
     rng = np.random.default_rng(seed)
@@ -118,7 +123,7 @@ def _move_state(n, d, seed):
                  for x in (chg, active, cand_prev, klass, real))
 
 
-@pytest.mark.parametrize("shape", [(8, 128), (16, 256), (64, 512)])
+@pytest.mark.parametrize("shape", [(8, 128), (16, 256), (64, 512)] + NARROW)
 @pytest.mark.parametrize("seed", [0, 3])
 @pytest.mark.parametrize("mode", ["interpret", "ref"])
 def test_fused_move_matches_separate_dispatch(shape, seed, mode):
@@ -146,7 +151,7 @@ def test_fused_move_matches_separate_dispatch(shape, seed, mode):
         assert int(new[0]) == int(cur[0])
 
 
-@pytest.mark.parametrize("shape", [(8, 128), (48, 256)])
+@pytest.mark.parametrize("shape", [(8, 128), (48, 256)] + NARROW)
 @pytest.mark.parametrize("prune", [True, False])
 @pytest.mark.parametrize("mode", ["interpret", "ref"])
 def test_fused_split_matches_separate_dispatch(shape, prune, mode):
@@ -182,7 +187,8 @@ def test_fused_split_matches_separate_dispatch(shape, prune, mode):
 def test_vmem_tile_budget():
     """ops.pick_tile_b must keep the equality cube within the VMEM budget:
     the 4 MB target where an 8-row tile fits it, else the 8-row minimum."""
-    for n_pad, d in [(1024, 128), (4096, 512), (65536, 1024), (40, 128)]:
+    for n_pad, d in [(1024, 128), (4096, 512), (65536, 1024), (40, 128),
+                     (65536, 8), (1024, 64)]:
         t = ops.pick_tile_b(n_pad, d)
         assert n_pad % t == 0
         assert t * d * d * 4 <= 4 * 1024 * 1024 or t == 8

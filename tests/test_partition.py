@@ -42,8 +42,8 @@ from repro.partition.slices import (
 FIXTURES = {
     "random": lambda: random_graph(220, 4.0, seed=3),
     "communities": lambda: _planted(),
-    # denser mix for the tile backend, whose (8, 128)-cell dense-tile
-    # floor (~9 KB/partition) needs in-core bytes comfortably above it
+    # denser mix for the tile backend, whose (8, d_bucket)-cell dense-tile
+    # floor per partition needs in-core bytes comfortably above it
     "tile_mix": lambda: random_graph(256, 10.0, seed=21),
 }
 

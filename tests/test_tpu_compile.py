@@ -65,14 +65,19 @@ def _kernel_args(sharding, name, n, d):
 
 
 @pytest.mark.parametrize("n,d", [(1024, 128), (1024, MAX_TILE_DEGREE),
-                                 (120, 128)],
-                         ids=["d128", "widest", "exact_n120"])
+                                 (120, 128), (1024, 8), (1024, 16),
+                                 (1024, 32), (1024, 64), (65536, 8),
+                                 (120, 8)],
+                         ids=["d128", "widest", "exact_n120", "d8", "d16",
+                              "d32", "d64", "road_d8", "exact_n120_d8"])
 @pytest.mark.parametrize("name", ["label_argmax", "min_label", "fused_move",
                                   "fused_split"])
 def test_kernel_compiles_for_v5e(one_chip, name, n, d):
     """Each tile kernel compiles to a Mosaic custom call at the tile that
-    pick_tile_b chooses, at d=128, at the widest admitted width, and at
-    the 120-row shape that ``bucketing="exact"`` produces."""
+    pick_tile_b chooses, at d=128, at the widest admitted width, at the
+    narrow widths below 128 that degree-bounded graphs get (road-256's
+    65,536 x 8 among them), and at the 120-row shape that
+    ``bucketing="exact"`` produces."""
     fn, args, kw = _kernel_args(one_chip, name, n, d)
     compiled = fn.lower(*args, mode="pallas", **kw).compile()
     assert "tpu_custom_call" in compiled.as_text()
@@ -81,7 +86,7 @@ def test_kernel_compiles_for_v5e(one_chip, name, n, d):
 def test_pick_tile_b_is_mosaic_aligned():
     """Every tile is a multiple of 8 that divides n_pad, or n_pad itself."""
     for n_pad in (8, 16, 24, 40, 56, 120, 136, 1000, 1024, 4096, 12, 5, 1):
-        for d in (128, 256, 384, 512, 640, 1024):
+        for d in (8, 16, 32, 64, 128, 256, 384, 512, 640, 1024):
             t = pick_tile_b(n_pad, d)
             assert n_pad % t == 0, (n_pad, d, t)
             assert t % 8 == 0 or t == n_pad, (n_pad, d, t)
@@ -119,15 +124,18 @@ def test_largest_admitted_tile_plan_fits_hbm(one_chip, n_bucket, d):
         assert total <= V5E_HBM_BYTES // 2, (name, total)
 
 
-def test_tile_programs_name_kernels_and_sweep_scopes(one_chip):
-    """The fused tile programs compiled for a v5e keep the kernel op names
+@pytest.mark.parametrize("bucket", [BucketKey(1024, 4096, 128),
+                                    BucketKey(65536, 262144, 8)],
+                         ids=["d128", "road_d8"])
+def test_tile_programs_name_kernels_and_sweep_scopes(one_chip, bucket):
+    """The fused tile programs compiled for a v5e, at 128-wide rows and at
+    the 8-wide rows of a degree-4 road graph, keep the kernel op names
     the trace readers match (``fused_move``, ``fused_split`` and their
     numbered copies) and carry the sweep scopes in their metadata."""
     import re
     plan = get_backend("tile").build(
-        BucketKey(1024, 4096, 128),
-        EngineConfig(kernel_mode="pallas", fuse_sweeps="on"))
-    r, d = plan.rows, 128
+        bucket, EngineConfig(kernel_mode="pallas", fuse_sweeps="on"))
+    r, d = plan.rows, bucket.d
     tiles = (_spec(one_chip, (r, d), jnp.int32),
              _spec(one_chip, (r, d), jnp.float32),
              _spec(one_chip, (r, d), jnp.bool_))
