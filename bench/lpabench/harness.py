@@ -12,7 +12,8 @@ Order of a run:
    seed and its warm-up of every shape the window will use;
 3. the window, ``--seconds`` long, under the host annotation
    ``bench.window`` and, with ``--trace 1``, under the profiler; compiles
-   inside it are counted and printed;
+   inside it are counted and printed with the cell's effective ``params``
+   and the traffic module's own information (``{"info": "window"}``);
 4. the device's peak memory is read, the program's state is dropped, and
    the traffic module compares what the window produced with the plain reference;
 5. the last line of standard output is one JSON object: ``correct``,
@@ -221,7 +222,8 @@ def execute(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     win, summary = traced_window(run, traffic, state)
     after = counter.snapshot()
     in_window = {k: after[k] - before[k] for k in after}
-    _emit_info("window", {"compiles_in_window": in_window, **win.info})
+    _emit_info("window", {"compiles_in_window": in_window,
+                          "params": cell.config["params"], **win.info})
 
     device["memory_peak_bytes"] = memory_peak(cell.chips)
     if summary is not None:

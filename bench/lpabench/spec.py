@@ -4,7 +4,10 @@ Everything that belongs to one configuration, traffic mix, generator or
 per-layer metric sits in a file of its own under the benchmark directory:
 
     configs/<config>.json       sizes, source, ``reduced``, ``assumed``
-    workloads/<cell>.json       config, traffic kind and parameters, chips
+    workloads/<cell>.json       config, traffic kind and parameters, chips,
+                                and optionally ``params``: sizes that
+                                override the configuration's, each a key
+                                of its ``reduced`` list
     traffic/<kind>.py           the code of one traffic kind
     generators/<family>.py      one graph family's generator
     metrics/<metric>.py         the reader of one per-layer metric
@@ -79,16 +82,33 @@ def _applies(metric: dict, cell: str, e2e_names: set) -> bool:
     return metric.get("moves", metric["name"]) in e2e_names
 
 
+def effective_config(workload: dict, config: dict) -> dict:
+    """The configuration with the workload's ``params`` merged into its
+    ``params``.  A workload may only move the configuration's cuts of
+    scale (its ``reduced`` keys), never a shape such as a degree, a weight
+    rule or a generator's initiator."""
+    params = workload.get("params", {})
+    for key in params:
+        if key not in config["reduced"]:
+            raise ValueError(
+                f"workload params key {key!r} is not in configuration "
+                f"{config['name']!r}'s reduced list {config['reduced']}")
+    return {**config, "params": {**config["params"], **params}}
+
+
 def load_cell(name: str, bench_dir: Path = BENCH_DIR,
               benchmark: dict | None = None) -> Cell:
     """The cell's workload and configuration, and the metrics it reports.
 
+    ``Cell.config["params"]`` are the effective sizes: the configuration's,
+    overridden by the workload's ``params`` (``effective_config``).
     ``benchmark`` defaults to ``BENCHMARK.json`` beside ``bench_dir``; a cell
     that the file does not list reports ``setup_s`` and every end-to-end
     metric without a ``workloads`` key.
     """
     workload = read_json(bench_dir / "workloads" / f"{name}.json")
-    config = read_json(bench_dir / "configs" / f"{workload['config']}.json")
+    config = effective_config(workload, read_json(
+        bench_dir / "configs" / f"{workload['config']}.json"))
     if benchmark is None:
         path = bench_dir.parent / "BENCHMARK.json"
         benchmark = read_json(path) if path.is_file() else {}

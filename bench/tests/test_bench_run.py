@@ -3,20 +3,25 @@
 The harness refuses the CPU in a real run; these tests pass
 ``allow_cpu=True`` to ``harness.execute`` to drive the rest of a run --
 set-up, window, reference comparison and the result line -- on tiny copies
-of the cells that live in a temporary directory, which also shows that a
-cell is added by adding files.
+of the cells that live in a temporary directory.  The whole-run tests take
+one case for each cell of ``BENCHMARK.json`` (its tiny copy, from
+``tests/tiny/<cell>.json``); the fault tests name the tiny cells they plant
+faults in.
 """
 from __future__ import annotations
 
 import json
 import os
+import shutil
 import subprocess
 import sys
 
 import pytest
-from tiny_cells import BENCH, ROOT, run_tiny
+from test_bench_files import (check_benchmark, check_cell, check_config,
+                              check_metric)
+from tiny_cells import BENCH, BENCHMARK, ROOT, run_tiny, tiny_names
 
-from lpabench import graphs, harness
+from lpabench import graphs, harness, spec
 
 KEYS = ["correct", "attempted", "failed", "metrics", "device"]
 
@@ -39,9 +44,18 @@ def fresh_plans(monkeypatch):
     monkeypatch.setattr(engine_mod, "GLOBAL_CACHE", CompileCache())
 
 
-@pytest.mark.parametrize("cell", ["tiny-g500.oneshot", "tiny-road.oneshot"])
-def test_untraced_run_line(tmp_path, cell):
+def _window_info(out: str) -> dict:
+    return next(d for d in (json.loads(line) for line in out.splitlines()
+                            if line.startswith('{"info"'))
+                if d["info"] == "window")
+
+
+@pytest.mark.parametrize("cell", tiny_names())
+def test_untraced_run_line(tmp_path, cell, capsys):
     res = run_tiny(tmp_path / "b", cell)
+    tiny = spec.load_cell(cell, tmp_path / "b", {})
+    assert _window_info(capsys.readouterr().out)["params"] == \
+        tiny.config["params"]
     assert list(res)[:5] == KEYS and list(res)[-1] == "checks"
     assert set(res) == set(KEYS) | {"checks"}
     assert res["correct"] is True and res["failed"] == 0
@@ -56,7 +70,7 @@ def test_untraced_run_line(tmp_path, cell):
     json.dumps(res)
 
 
-@pytest.mark.parametrize("cell", ["tiny-g500.oneshot", "tiny-road.oneshot"])
+@pytest.mark.parametrize("cell", tiny_names())
 def test_traced_run_line(tmp_path, cell):
     res = run_tiny(tmp_path / "b", cell, trace=True)
     assert list(res) == KEYS + ["breakdown", "checks"]
@@ -124,7 +138,8 @@ def test_fits_take_the_graphs_in_turn(tmp_path, monkeypatch):
     state = traffic.setup(run)
     assert len(state["graphs"]) == run.cell.traffic["graphs"]
     state["graphs"] = state["graphs"][:2]
-    clock = iter([0.0, 1.0, 2.0, 20.0])     # the window closes in fit 3
+    # the window's start, then each fit's start and end: it closes in fit 3
+    clock = iter([0.0, 0.0, 1.0, 1.0, 2.0, 2.25, 20.0])
     monkeypatch.setattr(traffic, "time",
                         SimpleNamespace(perf_counter=lambda: next(clock)))
     win = traffic.window(run, state)
@@ -132,6 +147,26 @@ def test_fits_take_the_graphs_in_turn(tmp_path, monkeypatch):
     edges = [state["graphs"][i].num_edges for i in (0, 1, 0)]
     assert win.info["fit_edges"] == edges
     assert win.end_to_end["edges_per_s"] == sum(edges) / 20.0
+    assert (win.info["fit_s_median"], win.info["fit_s_max"],
+            win.info["fit_gap_s_max"]) == (1.0, 17.75, 0.25)
+    assert win.info["fit_s"] == [1.0, 1.0, 17.75]
+
+
+def test_window_times_its_fits(tmp_path):
+    run, traffic = _oneshot(tmp_path, "tiny-road.oneshot", seconds=0.5)
+    info = traffic.window(run, traffic.setup(run)).info
+    assert 0 < info["fit_s_median"] <= info["fit_s_max"] <= info["window_s"]
+    assert 0 <= info["fit_gap_s_max"] < info["window_s"]
+    assert info["fit_s_median"] * info["fits"] <= info["window_s"] * 2
+    assert info["fit_s_max"] + info["fit_gap_s_max"] <= info["window_s"]
+    assert info["window_s"] >= 0.5
+    assert set(info["fit_max_timings"]) >= {"prepare", "compact"}
+    assert sum(info["fit_max_timings"].values()) <= info["fit_s_max"]
+    assert len(info["fit_s"]) == info["fits"]
+    assert max(info["fit_s"]) == info["fit_s_max"]
+    assert sum(info["fit_s"]) + info["fit_gap_s_max"] <= info["window_s"]
+    assert set(info["stage_s_median"]) == set(info["fit_max_timings"])
+    assert sum(info["stage_s_median"].values()) <= info["fit_s_max"]
 
 
 def test_each_fit_is_held_to_its_own_graph(tmp_path):
@@ -159,3 +194,51 @@ def test_no_tpu_exits_nonzero_without_a_result():
     assert proc.returncode != 0
     assert '"correct"' not in proc.stdout
     assert "no TPU" in proc.stderr
+
+
+def test_a_cell_is_new_files(tmp_path, capsys):
+    """A cell made of new files alone -- a workload that sets its
+    configuration's ``side`` through ``params``, its tiny file and its
+    entries in ``BENCHMARK.json`` -- loads, passes the file checks and runs
+    tiny and correct, in a copy of the checkout."""
+    src = tmp_path / "checkout"
+    shutil.copytree(BENCH, src / "bench", ignore=shutil.ignore_patterns(
+        ".cache", "__pycache__"))
+    shutil.copy(ROOT / "PERF.md", src)
+    benchmark = json.loads(json.dumps(BENCHMARK))
+    cfg = next(c for c in benchmark["configs"] if "side" in c["reduced"])
+    like = next(w for w in benchmark["workloads"]
+                if w["config"] == cfg["name"])
+    cell = "side-24.oneshot"
+    why = "two 24x24 graphs: a cell added as files"
+    entry = {"name": cell, "config": cfg["name"], "traffic": "oneshot",
+             "chips": 1, "why": why}
+    (src / "bench" / "workloads" / f"{cell}.json").write_text(json.dumps(
+        {"config": cfg["name"], "traffic": {"kind": "oneshot", "graphs": 2},
+         "chips": 1, "params": {"side": 24}, "why": why}))
+    (src / "bench" / "tests" / "tiny" / f"{cell}.json").write_text(
+        json.dumps({"name": "tiny-side-24.oneshot"}))
+    benchmark["workloads"].append(entry)
+    for m in benchmark["end_to_end"] + benchmark["per_layer"]:
+        if like["name"] in m.get("workloads", ()):
+            m["workloads"].append(cell)
+    (src / "BENCHMARK.json").write_text(json.dumps(benchmark))
+
+    c = check_cell(entry, src / "bench", benchmark)
+    raw = json.loads((ROOT / cfg["file"]).read_text())
+    assert c.config["params"] == {**raw["params"], "side": 24}
+    for config in benchmark["configs"]:
+        check_config(config, src)
+    for metric in benchmark["per_layer"]:
+        check_metric(metric, src / "bench", benchmark)
+    check_benchmark(benchmark, src)
+
+    res = run_tiny(tmp_path / "b", "tiny-side-24.oneshot",
+                   source=src / "bench", benchmark=benchmark)
+    assert res["correct"] is True
+    assert res["checks"]["label_mismatch_vertices"] == {"value": 0,
+                                                        "limit": 0}
+    info = _window_info(capsys.readouterr().out)
+    assert info["params"]["side"] == 24 and info["fit_n"][0] == 24 * 24
+    assert not (BENCH / "workloads" / f"{cell}.json").exists()
+    assert not (BENCH / "tests" / "tiny" / f"{cell}.json").exists()

@@ -15,11 +15,18 @@ the window over the time from the window's start to the end of its last
 fit.  Everything inside ``Engine.fit`` is inside the window: padding,
 transfers, the sweeps and compaction.
 
+The window's information line also gives each fit's seconds, the median
+and the longest fit, the longest gap between fits (``fit_times``), the
+engine's stage timings of the longest fit and each stage's median over
+the fits (``stage_medians``), so that a host stall or a slower host can
+be told from a seed that needs more sweeps, and placed in a stage.
+
 Correct: every fit's labels equal the plain reference's for its graph,
 vertex for vertex.
 """
 from __future__ import annotations
 
+import statistics
 import time
 
 from lpabench import graphs, reference
@@ -68,18 +75,20 @@ def setup(run):
 
 def window(run, state) -> Window:
     engine, built = state["engine"], state["graphs"]
-    fits, order = [], []
+    fits, order, starts, ends = [], [], [], []
     t0 = time.perf_counter()
     deadline = t0 + run.seconds
     while True:
         i = len(fits) % len(built)
+        starts.append(time.perf_counter())
         with run.span("bench.fit"):
             fits.append(engine.fit(built[i]))
         order.append(i)
-        t_end = time.perf_counter()
-        if t_end >= deadline:
+        ends.append(time.perf_counter())
+        if ends[-1] >= deadline:
             break
-    elapsed = t_end - t0
+    elapsed = ends[-1] - t0
+    longest = max(range(len(fits)), key=lambda k: ends[k] - starts[k])
     fit_n = [built[i].n for i in order]
     fit_edges = [built[i].num_edges for i in order]
     return Window(
@@ -90,7 +99,32 @@ def window(run, state) -> Window:
               "backend": fits[0].backend, "bucket": list(fits[0].bucket),
               "lpa_iterations": [r.lpa_iterations for r in fits],
               "split_iterations": [r.split_iterations for r in fits],
-              "communities": [r.num_communities for r in fits]})
+              "communities": [r.num_communities for r in fits],
+              **fit_times(t0, starts, ends),
+              "fit_max_timings": fits[longest].timings,
+              "stage_s_median": stage_medians(fits)})
+
+
+def fit_times(t0: float, starts: list, ends: list) -> dict:
+    """Each fit's time and the host's time between fits, so that a stall
+    (a fit or a gap far above the median) shows in a run's output.  The
+    fits and the gaps, the first from the window's start, add up to
+    ``window_s``."""
+    fit_s = [e - s for s, e in zip(starts, ends)]
+    gaps = [s - e for s, e in zip(starts, [t0] + ends[:-1])]
+    return {"fit_s_median": statistics.median(fit_s),
+            "fit_s_max": max(fit_s), "fit_gap_s_max": max(gaps),
+            "fit_s": fit_s}
+
+
+def stage_medians(fits: list) -> dict:
+    """The median over the window's fits of each engine stage's seconds:
+    ``prepare`` and ``compact`` are host work, ``propagation`` and
+    ``split`` wait on the device, so a slower host shows apart from a
+    seed that needs more sweeps."""
+    stages = sorted(set().union(*(r.timings for r in fits)))
+    return {k: statistics.median(r.timings.get(k, 0.0) for r in fits)
+            for k in stages}
 
 
 def check(run, state, win: Window):
