@@ -530,40 +530,43 @@ class Engine:
             weights = work / work.sum() if work.sum() > 0 \
                 else np.full(len(graphs), 1.0 / len(graphs))
 
-            results = []
-            for i, graph in enumerate(graphs):
-                lo, hi = int(batch.offsets[i]), int(batch.offsets[i + 1])
-                labels = labels_all[lo:hi]
-                w = float(weights[i])
+            # Per member after the one dispatch: unpack its rows, compact
+            # its labels and build its result.
+            with span("engine.members", k=len(graphs)):
+                results = []
+                for i, graph in enumerate(graphs):
+                    lo, hi = int(batch.offsets[i]), int(batch.offsets[i + 1])
+                    labels = labels_all[lo:hi]
+                    w = float(weights[i])
 
-                split_host = 0.0
-                if cfg.split == "bfs_host":
-                    with span("engine.split_host") as host_split:
-                        labels = split_bfs_host(graph, labels)
-                    split_host = host_split.dur
+                    split_host = 0.0
+                    if cfg.split == "bfs_host":
+                        with span("engine.split_host") as host_split:
+                            labels = split_bfs_host(graph, labels)
+                        split_host = host_split.dur
 
-                with span("engine.compact") as compact:
-                    labels, k = _compact_host(labels)
+                    with span("engine.compact") as compact:
+                        labels, k = _compact_host(labels)
 
-                result = DetectionResult(
-                    labels=labels, num_communities=k, backend=name,
-                    lpa_iterations=int(run.lpa_iterations[i]),
-                    split_iterations=int(run.split_iterations[i]),
-                    edge_slots=run.edge_slots,
-                    timings={"prorated_prepare": prepare.dur * w,
-                             "prorated_propagation": run.lpa_seconds * w,
-                             "prorated_split": run.split_seconds * w,
-                             "split": split_host, "compact": compact.dur},
-                    bucket=tuple(bucket), cache_hit=cache_hit,
-                    warm_started=warm_r[i],
-                    batch_size=len(graphs), batch_index=i,
-                    profile=run.profile[i] if run.profile else None,
-                )
-                if cfg.compute_metrics:
-                    self._attach_metrics(result, graph)
-                if cfg.quality != "off":
-                    self._attach_quality(result, graph, labels_r[i])
-                results.append(result)
+                    result = DetectionResult(
+                        labels=labels, num_communities=k, backend=name,
+                        lpa_iterations=int(run.lpa_iterations[i]),
+                        split_iterations=int(run.split_iterations[i]),
+                        edge_slots=run.edge_slots,
+                        timings={"prorated_prepare": prepare.dur * w,
+                                 "prorated_propagation": run.lpa_seconds * w,
+                                 "prorated_split": run.split_seconds * w,
+                                 "split": split_host, "compact": compact.dur},
+                        bucket=tuple(bucket), cache_hit=cache_hit,
+                        warm_started=warm_r[i],
+                        batch_size=len(graphs), batch_index=i,
+                        profile=run.profile[i] if run.profile else None,
+                    )
+                    if cfg.compute_metrics:
+                        self._attach_metrics(result, graph)
+                    if cfg.quality != "off":
+                        self._attach_quality(result, graph, labels_r[i])
+                    results.append(result)
         self._m_batch_fits.inc()
         self._m_fits.inc(len(graphs))
         return results

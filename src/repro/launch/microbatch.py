@@ -192,19 +192,23 @@ class MicroBatcher:
             item = self._q.get()
             if item is None:
                 break
-            batch = [item]
-            deadline = time.perf_counter() + self.batch_timeout_s
-            while len(batch) < self.max_batch:
-                remaining = deadline - time.perf_counter()
-                try:
-                    nxt = self._q.get_nowait() if remaining <= 0 \
-                        else self._q.get(timeout=remaining)
-                except queue.Empty:
-                    break
-                if nxt is None:
-                    stop = True
-                    break
-                batch.append(nxt)
+            # From the first request taken to dispatch: the linger that
+            # lets concurrent traffic coalesce into one batch.
+            with span("batch.collect") as collect:
+                batch = [item]
+                deadline = time.perf_counter() + self.batch_timeout_s
+                while len(batch) < self.max_batch:
+                    remaining = deadline - time.perf_counter()
+                    try:
+                        nxt = self._q.get_nowait() if remaining <= 0 \
+                            else self._q.get(timeout=remaining)
+                    except queue.Empty:
+                        break
+                    if nxt is None:
+                        stop = True
+                        break
+                    batch.append(nxt)
+                collect.set(size=len(batch))
             self._inflight = batch
             self._dispatch(batch)
             self._inflight = ()

@@ -497,6 +497,50 @@ def test_serving_emits_spans():
             "batch.dispatch", "batch.settle"} <= names
 
 
+@pytest.mark.parametrize("backend", BACKENDS)
+def test_batcher_and_member_spans_nest(backend):
+    """A batch's collection is a root span on the batcher's worker, before
+    its dispatch; the members' loop nests under ``engine.fit_many``, which
+    nests under ``batch.dispatch``.  Labels are bit-identical with the
+    tracer off."""
+    from repro.launch.microbatch import MicroBatcher
+    graphs = [erdos_renyi(n, 4.0, seed=i) for i, n in enumerate((60, 90, 75))]
+
+    def served():
+        with MicroBatcher(fresh_engine(backend=backend), max_batch=3,
+                          batch_timeout_ms=50, autostart=False) as mb:
+            subs = [mb.submit(g) for g in graphs]
+            mb.start()
+            return [s.result(timeout=300).labels for s in subs]
+
+    TRACER.reset()
+    labels = served()
+    by_name = {}
+    for s in TRACER.spans():
+        by_name.setdefault(s.name, []).append(s)
+    (collect,) = by_name["batch.collect"]
+    (dispatch,) = by_name["batch.dispatch"]
+    (fit_many,) = by_name["engine.fit_many"]
+    (members,) = by_name["engine.members"]
+    assert collect.parent_id == 0 and collect.attrs == {"size": 3}
+    assert collect.tid == dispatch.tid
+    assert collect.t0 + collect.dur <= dispatch.t0
+    assert fit_many.parent_id == dispatch.span_id
+    assert members.parent_id == fit_many.span_id
+    assert members.attrs == {"k": 3}
+    compacts = [s for s in by_name["engine.compact"]
+                if s.parent_id == members.span_id]
+    assert len(compacts) == 3
+    assert fit_many.t0 <= members.t0
+    assert members.t0 + members.dur <= fit_many.t0 + fit_many.dur
+    TRACER.enabled = False
+    try:
+        quiet = served()
+    finally:
+        TRACER.enabled = True
+    assert all(np.array_equal(a, b) for a, b in zip(labels, quiet))
+
+
 def test_scope_release_frees_child_labels():
     """Releasing a scope must free its children's labels too — a
     restarted service's sub-scopes get bare names, not #1 suffixes."""

@@ -13,7 +13,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.engine import EngineConfig
-from repro.engine.bucketing import BucketKey
+from repro.engine.bucketing import BatchBucketKey, BucketKey
 from repro.engine.registry import get_backend, tile_limit_error
 from repro.kernels import ops
 from repro.kernels.tiling import MAX_TILE_DEGREE, pick_tile_b
@@ -158,3 +158,43 @@ def test_tile_programs_name_kernels_and_sweep_scopes(one_chip, bucket):
             assert "/sweep.reduce/" in line, name
         for scope in ("sweep.gather", "sweep.wake"):
             assert f"/{scope}/" in text, (kernel, scope)
+
+
+@pytest.mark.parametrize("bucket", [BatchBucketKey(128, 16384, 1 << 20, 1024)],
+                         ids=["ego_k128_d1024"])
+def test_batched_tile_programs_compile_at_hub_widths(one_chip, bucket):
+    """The fused batched propagate and split programs at the ego-network
+    cell's largest bucket (128 members, 16,384 rows, 1,024-wide rows)
+    compile for one v5e, keep the kernel names the trace readers match,
+    carry the sweep scopes, and fit in the chip's HBM."""
+    import re
+    plan = get_backend("tile").build_batch(
+        bucket, EngineConfig(kernel_mode="pallas", fuse_sweeps="on"))
+    r, d, k1 = plan.rows, bucket.d, bucket.k + 1
+    i32, b = jnp.int32, jnp.bool_
+    tile = lambda dt: _spec(one_chip, (r, d), dt)  # noqa: E731
+    col = lambda dt: _spec(one_chip, (r,), dt)  # noqa: E731
+    index = (_spec(one_chip, (k1,), i32), col(i32), col(i32))
+    programs = {
+        "fused_move": plan.propagate.lower(
+            tile(i32), tile(jnp.float32), tile(b), *index,
+            _spec(one_chip, (), i32), col(i32), col(b)),
+        "fused_split": plan.split.lower(tile(i32), tile(b), *index,
+                                        col(i32)),
+    }
+    for kernel, lowered in programs.items():
+        compiled = lowered.compile()
+        text = compiled.as_text()
+        calls = [line for line in text.splitlines()
+                 if "tpu_custom_call" in line and " = " in line]
+        assert calls, kernel
+        for line in calls:
+            name = line.split(" = ")[0].replace("ROOT", "").strip()
+            assert re.fullmatch(rf"%{kernel}(\.\d+)?", name), name
+            assert "/sweep.reduce/" in line, name
+        for scope in ("sweep.gather", "sweep.wake"):
+            assert f"/{scope}/" in text, (kernel, scope)
+        ma = compiled.memory_analysis()
+        total = (ma.argument_size_in_bytes + ma.temp_size_in_bytes
+                 + ma.output_size_in_bytes)
+        assert total <= V5E_HBM_BYTES // 2, (kernel, total)
