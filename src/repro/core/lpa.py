@@ -2,24 +2,30 @@
 
 The paper's ``lpaMove`` accumulates per-neighbor-community weights in
 per-thread hashtables.  Hashtables do not vectorise; the TPU-native
-formulation here is **sort + segment-reduce** (the same family of tricks the
-paper cites for GPU LPA [Soman & Narang, bitonic sort]):
+formulation here is **sort + segmented scans** (the same family of tricks
+the paper cites for GPU LPA [Soman & Narang, bitonic sort]):
 
   1. for every directed edge (u, v, w) form the key pair (u, C[v]);
   2. lexicographically sort edges by that pair (``lax.sort`` with 2 keys —
      no 64-bit packing, so it works under JAX's default 32-bit ints);
-  3. segment-sum weights over key runs -> K_{u -> c} for every (u, c) that
-     actually occurs;
-  4. per-source segment-max over the run sums -> best community weight, with
-     deterministic tie-breaks: max weight, then max label-hash (a per-
-     iteration integer mix).  The paper's hashtable iteration order is
-     effectively random among equal-weight labels; a *fixed* min-label
-     tie-break would cascade every unweighted graph into one monster
-     community, so we keep randomness but make it a pure function of
-     (label, iteration) — bit-reproducible across runs and hosts;
+  3. a segmented sum of the weights within each key run -> K_{u -> c} at
+     the run's last position, for every (u, c) that actually occurs;
+  4. per-source segmented maxima over the run ends -> best community
+     weight, with deterministic tie-breaks: max weight, then max
+     label-hash (a per-iteration integer mix).  The paper's hashtable
+     iteration order is effectively random among equal-weight labels; a
+     *fixed* min-label tie-break would cascade every unweighted graph into
+     one monster community, so we keep randomness but make it a pure
+     function of (label, iteration) — bit-reproducible across runs and
+     hosts;
   5. a vertex adopts the best label only if it is *strictly* better connected
      than its current label (prevents synchronous-update oscillation and
      makes runs bit-reproducible — see DESIGN.md §2 "Determinism").
+
+The sorted order keeps each source's edges at its CSR range
+``[row_ptr[u], row_ptr[u+1])``, so steps 3-5 are scans over that order
+(``core.rowscan``) read at the row ends: no scatter, and no gather with an
+index per edge, besides the neighbour-label read of step 1.
 
 Vertex pruning (the paper's processed/unprocessed flags) is a dense boolean
 ``active`` mask: masked vertices keep their label; a vertex is reactivated
@@ -40,13 +46,13 @@ only in the compiled program's ``op_name`` metadata; HLO op and module
 names, and the run time, are unchanged:
 
 * ``sweep.gather`` — neighbour-indexed reads (``labels[graph.dst]``,
-  ``labels[nbr]``, ``best_w[seg_src]``, ...) and the expressions fused
-  around them;
+  ``labels[nbr]``, ...) and the expressions fused around them;
 * ``sweep.sort`` — the (source, label) sort of ``_scan_communities``;
-* ``sweep.reduce`` — the per-row reductions and the adopt rule: segment
-  reductions and the Pallas row kernels;
+* ``sweep.reduce`` — the per-row reductions and the adopt rule: segmented
+  scans, row broadcasts and the Pallas row kernels;
 * ``sweep.wake`` — the frontier: changed masks, their counts, and which
-  rows run next (``neighbors_of``, ``jnp.any(changed[nbr] & ...)``).
+  rows run next (``neighbors_of``, ``jnp.any(changed[nbr] & ...)``),
+  their neighbour reads and scans included.
 """
 from __future__ import annotations
 
@@ -56,9 +62,11 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.core import rowscan
 from repro.core.graph import Graph
 
 _NEG = jnp.float32(-1.0)  # weights are positive; -1 marks "no run"
+_INT_MAX = jnp.iinfo(jnp.int32).max  # above every label: "no label"
 
 
 class LpaState(NamedTuple):
@@ -70,9 +78,12 @@ class LpaState(NamedTuple):
 
 def _scan_communities(graph: Graph, labels: jnp.ndarray,
                       label_bound: jnp.ndarray | int | None = None):
-    """Steps 1-3: per-(src, community) connecting weights via sort+segments.
+    """Steps 1-3: per-(src, community) connecting weights via sort+scans.
 
-    Returns (run_src, run_label, run_wgt, run_valid), each (m_pad,).
+    Returns (src_s, lab_s, run_wgt, run_end), each (m_pad,), in the sorted
+    edge order: the sources and neighbour labels, each run's weight summed
+    up to each position, and which positions end a valid run (there
+    ``run_wgt`` is the run's whole weight).
 
     ``label_bound``: exclusive upper bound on real label *values*, used as
     the padding sentinel.  Defaults to ``graph.n`` — the solo/in-core case
@@ -81,7 +92,7 @@ def _scan_communities(graph: Graph, labels: jnp.ndarray,
     are *global* vertex ids, so the bound there is the full graph's vertex
     count (may be traced; one executable serves every partition).
     """
-    n, m_pad = graph.n, graph.m_pad
+    n = graph.n
     bound = n if label_bound is None else label_bound
     with jax.named_scope("sweep.gather"):
         # Padding edges get the label sentinel so they sort last and
@@ -99,15 +110,11 @@ def _scan_communities(graph: Graph, labels: jnp.ndarray,
         prev_lab = jnp.concatenate([jnp.full((1,), -1, jnp.int32),
                                     lab_s[:-1]])
         is_start = (src_s != prev_src) | (lab_s != prev_lab)
-        run_id = jnp.cumsum(is_start.astype(jnp.int32)) - 1  # in [0, R)
-
-        run_wgt = jax.ops.segment_sum(wgt_s, run_id, num_segments=m_pad)
-        run_src = jax.ops.segment_max(src_s, run_id, num_segments=m_pad)
-        run_lab = jax.ops.segment_max(lab_s, run_id, num_segments=m_pad)
-        run_valid = (jax.ops.segment_max(is_start.astype(jnp.int32), run_id,
-                                         num_segments=m_pad) > 0)
-        run_valid &= (run_lab < bound) & (run_src < n)
-    return run_src, run_lab, run_wgt, run_valid
+        run_wgt = rowscan.segmented_scan(rowscan.run_keys(is_start), wgt_s,
+                                         jnp.add, 0.0)
+        run_end = jnp.concatenate([is_start[1:], jnp.ones((1,), bool)])
+        run_end &= (lab_s < bound) & (src_s < n)
+    return src_s, lab_s, run_wgt, run_end
 
 
 def _label_hash(labels: jnp.ndarray, iteration: jnp.ndarray) -> jnp.ndarray:
@@ -123,9 +130,9 @@ def _label_hash(labels: jnp.ndarray, iteration: jnp.ndarray) -> jnp.ndarray:
 def neighbors_of(graph: Graph, mask: jnp.ndarray) -> jnp.ndarray:
     """Boolean mask of vertices adjacent to any vertex in ``mask``."""
     with jax.named_scope("sweep.wake"):
-        return jax.ops.segment_max(
-            (mask[graph.dst] & graph.edge_mask).astype(jnp.int32),
-            graph.src, num_segments=graph.n) > 0
+        hit = (mask[graph.dst] & graph.edge_mask).astype(jnp.int32)
+        return rowscan.row_reduce(rowscan.row_keys(graph), hit,
+                                  graph.row_ptr, jnp.maximum, 0) > 0
 
 
 def lpa_move(graph: Graph, labels: jnp.ndarray, active: jnp.ndarray,
@@ -137,39 +144,36 @@ def lpa_move(graph: Graph, labels: jnp.ndarray, active: jnp.ndarray,
     Returns (new_labels, changed_mask, delta_n).  ``label_bound``: see
     :func:`_scan_communities` — only the partition path passes it.
     """
-    n = graph.n
-    bound = n if label_bound is None else label_bound
-    run_src, run_lab, run_wgt, run_valid = _scan_communities(graph, labels,
-                                                             label_bound)
+    bound = graph.n if label_bound is None else label_bound
+    src_s, lab_s, run_wgt, run_end = _scan_communities(graph, labels,
+                                                       label_bound)
     with jax.named_scope("sweep.reduce"):
-        # dump invalid runs on a real id
-        seg_src = jnp.where(run_valid, run_src, n - 1)
-        w = jnp.where(run_valid, run_wgt, _NEG)
-
         # Step 4: per-source best community weight; tie-break max label
-        # hash.
-        best_w = jax.ops.segment_max(w, seg_src, num_segments=n)
-        with jax.named_scope("sweep.gather"):
-            is_best = (run_valid & (run_wgt >= best_w[seg_src])
-                       & (best_w[seg_src] > 0))
-        run_h = _label_hash(run_lab, jnp.asarray(iteration, jnp.int32))
-        best_h = jax.ops.segment_max(jnp.where(is_best, run_h, -1), seg_src,
-                                     num_segments=n)
-        with jax.named_scope("sweep.gather"):
-            pick = is_best & (run_h == best_h[seg_src])
-        best_lab = jax.ops.segment_min(jnp.where(pick, run_lab, bound),
-                                       seg_src, num_segments=n)
+        # hash, then min label.  A source's edges are its row block.
+        w = jnp.where(run_end, run_wgt, _NEG)
+        best_w = rowscan.block_total(src_s, w, jnp.maximum, _NEG)
+        is_best = run_end & (w >= best_w) & (best_w > 0)
+        run_h = _label_hash(lab_s, jnp.asarray(iteration, jnp.int32))
+        best_h = rowscan.block_total(src_s, jnp.where(is_best, run_h, -1),
+                                     jnp.maximum, -1)
+        pick = is_best & (run_h == best_h)
+        best_lab = rowscan.segmented_scan(
+            src_s, jnp.where(pick, lab_s, _INT_MAX), jnp.minimum, _INT_MAX)
 
         # Connecting weight to the *current* community (keep unless
         # strictly worse).
-        with jax.named_scope("sweep.gather"):
-            to_cur = run_valid & (run_lab == labels[seg_src])
-        cur_w = jax.ops.segment_max(jnp.where(to_cur, run_wgt, _NEG),
-                                    seg_src, num_segments=n)
+        cur_lab = rowscan.row_broadcast(labels, graph.row_ptr, graph.m_pad)
+        cur_w = rowscan.segmented_scan(
+            src_s, jnp.where(run_end & (lab_s == cur_lab), run_wgt, _NEG),
+            jnp.maximum, _NEG)
 
-        adopt = (active & (best_lab < bound)
-                 & (best_w > jnp.maximum(cur_w, 0.0)))
-        new_labels = jnp.where(adopt, best_lab.astype(labels.dtype), labels)
+        # Step 5 at each row's last position, where the scans hold the
+        # whole row.
+        take = (best_lab < bound) & (best_w > jnp.maximum(cur_w, 0.0))
+        choice = rowscan.at_row_ends(jnp.where(take, best_lab, -1),
+                                     graph.row_ptr, -1)
+        adopt = active & (choice >= 0)
+        new_labels = jnp.where(adopt, choice.astype(labels.dtype), labels)
     with jax.named_scope("sweep.wake"):
         changed = new_labels != labels
         delta_n = jnp.sum(changed.astype(jnp.int32))

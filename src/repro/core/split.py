@@ -25,7 +25,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import rowscan
 from repro.core.graph import Graph, to_numpy_adj
+
+_INT_MAX = jnp.iinfo(jnp.int32).max  # above every label: "no neighbour"
 
 
 class SplitState(NamedTuple):
@@ -51,14 +54,17 @@ def _min_label_sweep(graph: Graph, comm: jnp.ndarray, labels: jnp.ndarray,
     labels are *global* vertex ids and passes the full graph's vertex
     count (traced — one executable serves every partition).
     """
-    n = graph.n
-    bound = n if label_bound is None else label_bound
+    bound = graph.n if label_bound is None else label_bound
+    with jax.named_scope("sweep.reduce"):
+        keys = rowscan.row_keys(graph)
+        comm_src = rowscan.row_broadcast(comm, graph.row_ptr, graph.m_pad)
     with jax.named_scope("sweep.gather"):
-        same = graph.edge_mask & (comm[graph.src] == comm[graph.dst])
+        same = graph.edge_mask & (comm_src == comm[graph.dst])
         # min over same-community neighbors; sentinel `bound` elsewhere
         cand = jnp.where(same, labels[graph.dst], bound).astype(jnp.int32)
     with jax.named_scope("sweep.reduce"):
-        nbr_min = jax.ops.segment_min(cand, graph.src, num_segments=n)
+        nbr_min = rowscan.row_reduce(keys, cand, graph.row_ptr, jnp.minimum,
+                                     _INT_MAX)
         new = jnp.minimum(labels, nbr_min.astype(labels.dtype))
         if prune:
             new = jnp.where(active, new, labels)
@@ -72,9 +78,9 @@ def _min_label_sweep(graph: Graph, comm: jnp.ndarray, labels: jnp.ndarray,
         if prune:
             # reactivate same-community neighbors of changed vertices
             # (lines 20-21)
-            nxt_active = jax.ops.segment_max(
-                (changed[graph.dst] & same).astype(jnp.int32), graph.src,
-                num_segments=n) > 0
+            nxt_active = rowscan.row_reduce(
+                keys, (changed[graph.dst] & same).astype(jnp.int32),
+                graph.row_ptr, jnp.maximum, 0) > 0
         else:
             nxt_active = active
     return new, nxt_active, changed, delta_n
@@ -171,10 +177,12 @@ def min_label_wake(graph: Graph, comm: jnp.ndarray,
     vertex's *own* neighborhood.
     """
     with jax.named_scope("sweep.wake"):
-        same = graph.edge_mask & (comm[graph.src] == comm[graph.dst])
-        return jax.ops.segment_max(
-            (changed[graph.dst] & same).astype(jnp.int32), graph.src,
-            num_segments=graph.n) > 0
+        comm_src = rowscan.row_broadcast(comm, graph.row_ptr, graph.m_pad)
+        same = graph.edge_mask & (comm_src == comm[graph.dst])
+        return rowscan.row_reduce(
+            rowscan.row_keys(graph),
+            (changed[graph.dst] & same).astype(jnp.int32), graph.row_ptr,
+            jnp.maximum, 0) > 0
 
 
 def split_bfs_host(graph: Graph, comm: np.ndarray) -> np.ndarray:

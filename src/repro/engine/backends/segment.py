@@ -1,4 +1,4 @@
-"""Segment backend: the CSR edge-list sort + segment-reduce path.
+"""Segment backend: the CSR edge-list sort + segmented-scan path.
 
 Wraps ``core.lpa.lpa_run`` (propagation) and ``core.split.split_lp``
 (Split-Last) behind the Backend protocol.  The plan's jitted wrappers

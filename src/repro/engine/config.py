@@ -24,7 +24,7 @@ QUALITY = ("off", "basic", "full")
 class EngineConfig:
     """Configuration for :class:`repro.engine.Engine`.
 
-    backend: execution strategy — ``"segment"`` (CSR sort+segment-reduce),
+    backend: execution strategy — ``"segment"`` (CSR sort + segmented scans),
       ``"tile"`` (padded-neighbor tiles / Pallas kernels), ``"sharded"``
       (multi-device shard_map), or ``"auto"`` (chosen per graph from size,
       max degree, and device count).

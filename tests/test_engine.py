@@ -1,5 +1,7 @@
 """Unified Engine API: backend parity, shape-bucketed compile cache,
 warm starts, and legacy-wrapper compatibility."""
+import re
+
 import numpy as np
 import pytest
 
@@ -324,3 +326,41 @@ def test_sweep_programs_carry_named_scopes(backend, fuse):
     for text, sort in ((propagate, backend == "segment"), (split, False)):
         found = {s for s in scopes if f"/{s}/" in text}
         assert found == set(scopes) - (set() if sort else {"sweep.sort"})
+
+
+def test_segment_scans_carry_reduce_and_wake_scopes():
+    """Every operation of the segmented scans (scope ``rowscan``, from
+    ``core/rowscan.py``) sits under ``sweep.reduce`` or ``sweep.wake`` in
+    the compiled programs' metadata, so ``reduce_ms`` and ``wake_ms``
+    still measure the per-row reductions and the frontier."""
+    for lowered in _sweep_programs("segment", "auto"):
+        names = re.findall(r'op_name="([^"]*/rowscan/[^"]*)"',
+                           lowered.compile().as_text())
+        assert len(names) > 20
+        for name in names:
+            scopes = re.findall(r"sweep\.(\w+)", name.split("/rowscan/")[0])
+            assert scopes and scopes[-1] in ("reduce", "wake"), name
+
+
+def _scatter_update_sizes(lowered):
+    """Element counts of the updates of every scatter in a lowered
+    program (its HLO before optimisation)."""
+    text = lowered.compiler_ir(dialect="hlo").as_hlo_text()
+    sizes = {}
+    for name, dims in re.findall(
+            r"^\s*(?:ROOT )?%?([\w.\-]+) = \w+\[([\d,]*)\]", text, re.M):
+        sizes[name] = int(np.prod([int(d) for d in dims.split(",") if d]))
+    return [sizes[ops.split(",")[-1].strip().lstrip("%")]
+            for ops in re.findall(r" scatter\(([^)]*)\)", text)]
+
+
+def test_segment_sweeps_scatter_nothing_per_edge_slot():
+    """The segment backend's propagation and split programs scatter no
+    update per edge slot: every per-row reduction is a segmented scan.
+    The one scatter left, a row broadcast's, has an update per vertex."""
+    from repro.engine.bucketing import bucket_for
+    bucket = bucket_for(erdos_renyi(100, 4.0, seed=8))
+    assert bucket.n < bucket.m
+    for lowered in _sweep_programs("segment", "auto"):
+        sizes = _scatter_update_sizes(lowered)
+        assert sizes and all(size <= bucket.n for size in sizes), sizes
